@@ -47,6 +47,7 @@ import numpy as np
 from repro.core import encode, prune_vectors_balanced, vs_conv2d, vs_matmul
 from repro.kernels import vsconv, vsmm
 from repro.kernels.ref import vsconv_ref, vsmm_ref
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _sparse(rng, k, n, vk, vn, density, dtype=jnp.float32):
@@ -667,6 +668,7 @@ if __name__ == "__main__":
                     help="write rows as a JSON artifact "
                          "(e.g. BENCH_resnet18.json)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.gate_traffic:
         raise SystemExit(gate_traffic())
     if args.compare_baseline:

@@ -70,6 +70,7 @@ from repro.configs import get_config
 from repro.launch.faults import ChaosBackend, FaultPlan
 from repro.launch.scheduler import FleetScheduler
 from repro.launch.serve import CNNServer, ImageRequest
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _requests(rng, n: int, size: int) -> list[ImageRequest]:
@@ -529,6 +530,7 @@ if __name__ == "__main__":
     ap.add_argument("--deadline-waves", type=int, default=None,
                     help="per-request deadline in fleet ticks (--chaos)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.chaos:
         art = run_chaos(args.arch, seeds=tuple(args.chaos_seeds),
                         replicas=args.chaos_replicas, images=args.images,

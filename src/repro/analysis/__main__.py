@@ -91,14 +91,13 @@ def run_selftest() -> bool:
     # last row-block's reads escape the padded buffer (VSC201)
     orig_halo = plan_mod.halo_in_index_map
 
-    def bad_halo(hb: int, stride: int, bh: int, cbg: int,
-                 spg: int) -> Callable:
-        inner = orig_halo(hb, stride, bh, cbg, spg)
+    def bad_halo(hb: int, bh: int, cbg: int, spg: int) -> Callable:
+        inner = orig_halo(hb, bh, cbg, spg)
 
         def index_map(j: object, m: object, s: object,
                       idx: object) -> tuple:
             o = inner(j, m, s, idx)
-            return (o[0], o[1] + stride * bh, *o[2:])
+            return (*o[:3], o[3] + bh, *o[4:])
         return index_map
 
     plan_mod.halo_in_index_map = bad_halo

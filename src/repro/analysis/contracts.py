@@ -106,7 +106,7 @@ def _offsets(plan: KernelPlan, buf: BufferAccess, idx: np.ndarray
     cols = [np.broadcast_to(np.asarray(o, dtype=np.int64), a0.shape)
             for o in out]
     offs = np.stack(cols, axis=1)
-    if not buf.unblocked:
+    if not buf.element:
         offs = offs * np.asarray(buf.block, dtype=np.int64)
     return offs
 
@@ -147,7 +147,7 @@ def _bounds_violations(plan: KernelPlan, buf: BufferAccess
     bad: list[tuple[int, Interval]] = []
     for ax, o in enumerate(out):
         iv = Interval.of(o)
-        if buf.unblocked:
+        if buf.element:
             ok = iv.lo >= 0 and iv.hi + buf.block[ax] <= buf.dims[ax]
         else:
             ok = iv.lo >= 0 and (iv.hi + 1) * buf.block[ax] <= buf.dims[ax]
@@ -278,8 +278,9 @@ def check_conv_site(site: ConvSite, *, rep: Report, itemsize: int = 4,
                    if site.has_residual else 0),
                 "flops": plan.flops_per_step * _prod(plan.grid)
                 * m_valid // mp,
-                "build": (2 * m_valid * c * itemsize
-                          if site.stride != 1 else 0),
+                # the K-tile-major transpose (fused with the stride-2
+                # subsample): read + write the activation once
+                "build": 2 * m_valid * c * itemsize,
             }
         else:
             in_dims = plan.buffer("input").dims

@@ -335,8 +335,8 @@ def conv_layer_traffic(
     the int8 path, matching the kernels' real CostEstimate.
     """
     from repro.kernels.vsconv import (  # lazy: keep accel_model numpy-first
-        dw_halo_kernel_cost, dw_stack_kernel_cost, halo_kernel_cost,
-        stack_kernel_cost, use_resident_halo,
+        dw_halo_kernel_cost, dw_stack_kernel_cost, halo_block_rows,
+        halo_kernel_cost, stack_kernel_cost, use_resident_halo,
     )
     from .sparse_ops import same_pads
 
@@ -357,8 +357,9 @@ def conv_layer_traffic(
 
     if kh == 1 and kw == 1 and groups == 1:
         # vsmm over flattened pixels: every sparse step gathers a fresh
-        # (bm, vk) activation K-tile; identical for both impls.  The
-        # stride-2 subsample is the only layout pass.
+        # (bm, vk) activation K-tile; identical for both impls.  The layout
+        # pass is the K-tile-major transpose (`kernels.vsmm.
+        # build_vsmm_input`), the stride-2 subsample fused into it.
         m = n * ho * wo
         flops = 2 * m * nb * s_steps * vk * vn
         return TrafficReport(
@@ -368,7 +369,7 @@ def conv_layer_traffic(
             weight_bytes=nb * s_steps * vk * vn * w_itemsize,
             output_bytes=(m * cout * out_itemsize
                           + (m * cout * out_itemsize if residual else 0)),
-            build_bytes=(2 * m * c * itemsize if stride != 1 else 0),
+            build_bytes=2 * m * c * itemsize,
         )
 
     bh = min(bh, ho)
@@ -378,8 +379,12 @@ def conv_layer_traffic(
     ke_h = (kh - 1) * dilation + 1
     ke_w = (kw - 1) * dilation + 1
     if impl == "halo":
-        rows = stride * (hop - 1) + ke_h
-        bwp = _round_up(stride * (wo - 1) + ke_w, 8)
+        # phase-split layout: stride**2 planes of (rows, bwp) each
+        rows = hop + (ke_h - 1) // stride
+        bwp = wo + (ke_w - 1) // stride
+        # one halo block: every phase plane's window of block rows
+        block = stride * stride * halo_block_rows(kh, stride, bh, dilation) \
+            * bwp
         if depthwise:
             assert vk == 1 and cout == c, (x_shape, cout, vk, groups)
             est = dw_halo_kernel_cost(
@@ -388,8 +393,7 @@ def conv_layer_traffic(
                 in_itemsize=itemsize, w_itemsize=w_itemsize,
                 out_itemsize=out_itemsize, residual_bytes=res_bytes,
             )
-            input_bytes = n * hb * nb * (stride * (bh - 1) + ke_h) * bwp \
-                * vn * itemsize
+            input_bytes = n * hb * nb * block * vn * itemsize
         else:
             cbg = cb // groups  # cin tiles reachable from one strip
             resident = use_resident_halo(hop, groups)
@@ -400,16 +404,15 @@ def conv_layer_traffic(
                 in_itemsize=itemsize, w_itemsize=w_itemsize,
                 out_itemsize=out_itemsize, residual_bytes=res_bytes,
             )
-            hh = stride * (bh - 1) + ke_h
             if resident:
                 # tiny-feature-map layout: the whole-cin halo block is
                 # fetched once per (image, row-block), never per strip
-                input_bytes = n * hb * hh * bwp * cb * vk * itemsize
+                input_bytes = n * hb * block * cb * vk * itemsize
             else:
-                input_bytes = (n * hb * nb * min(s_steps, cbg) * hh * bwp
+                input_bytes = (n * hb * nb * min(s_steps, cbg) * block
                                * vk * itemsize)
-        # one jnp.pad: read the input, write the padded copy
-        build = n * c * (h * w + rows * bwp) * itemsize
+        # one pad + phase-split transpose: read the input, write the copy
+        build = n * c * (h * w + stride * stride * rows * bwp) * itemsize
     elif impl == "stack":
         bw = _round_up(wo + ((kw - 1) * dilation) // stride, 8)
         if depthwise:

@@ -50,7 +50,10 @@ def _use_pallas(impl: str) -> bool:
         return True
     if impl == "jnp":
         return False
-    return jax.default_backend() == "tpu"
+    from repro.kernels import ops as kops  # lazy: avoid import cycle
+
+    # 'auto': the compiled kernels wherever they need no interpreter
+    return not kops._interpret()
 
 
 def _conv_impl(impl: str) -> str:

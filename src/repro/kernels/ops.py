@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.vector_sparse import VectorSparse
-from .vsmm import vsmm_pallas
+from .vsmm import build_vsmm_input, vsmm_pallas
 from .vsconv import (
     vsconv_pallas, vsconv_halo_pallas, vsconv_dw_halo_pallas,
     vsconv_dw_stack_pallas, build_row_tap_stack, build_halo_input,
@@ -45,6 +45,9 @@ __all__ = ["vsmm", "vsconv"]
 
 
 def _interpret() -> bool:
+    """True off the TPU: the kernels then run in interpret mode, and
+    ``impl="auto"`` (`core.sparse_ops`) takes the jnp path instead.  The
+    one place the backend decides how the sparse path runs."""
     return jax.default_backend() != "tpu"
 
 
@@ -71,7 +74,7 @@ def vsmm(
     shortcut) + ``fuse_relu`` inside the kernel (f32 accumulator, one cast
     at flush).
     """
-    m, k = x.shape
+    m = x.shape[0]
     interpret = _interpret() if interpret is None else interpret
     bm = min(bm, _round_up(m, 8))
     mp = _round_up(m, bm)
@@ -80,7 +83,8 @@ def vsmm(
         if residual is not None:
             residual = jnp.pad(residual, ((0, mp - m), (0, 0)))
     out = vsmm_pallas(
-        x, vs, bm=bm, bias=bias, residual=residual, scale=scale,
+        build_vsmm_input(x, vs.vk), vs, bm=bm, bias=bias, residual=residual,
+        scale=scale,
         skip_zero_inputs=skip_zero_inputs,
         fuse_relu=fuse_relu, interpret=interpret
     )

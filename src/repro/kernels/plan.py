@@ -32,7 +32,7 @@ DMA-counting policies (how the cost contract counts block fetches):
   ``per_step``        one DMA per grid step — the row-tap stack input and
                       the vsmm activation gather, whose block index
                       changes (in the model) every sparse step.
-  ``excluded``        not part of the byte contract (the (1, vn) bias
+  ``excluded``        not part of the byte contract (the (1, 1, vn) bias
                       and int8 dequant-scale tiles: one tile per strip,
                       noise next to the other terms) — bounds are still
                       proven.
@@ -52,9 +52,10 @@ from jax.experimental import pallas as pl
 from .vsconv import (
     conv_bias_index_map, conv_out_index_map, conv_weight_index_map,
     dw_halo_in_index_map, dw_halo_kernel_cost, dw_stack_in_index_map,
-    dw_stack_kernel_cost, halo_in_index_map, halo_kernel_cost,
-    halo_layout_dims, resident_in_index_map, same_pads, stack_in_index_map,
-    stack_kernel_cost, stack_layout_dims, use_resident_halo,
+    dw_stack_kernel_cost, halo_block_rows, halo_in_index_map,
+    halo_kernel_cost, halo_layout_dims, resident_in_index_map, same_pads,
+    stack_in_index_map, stack_kernel_cost, stack_layout_dims,
+    use_resident_halo,
 )
 from .vsmm import (
     vsmm_bias_index_map, vsmm_kernel_cost, vsmm_out_index_map,
@@ -81,8 +82,8 @@ class BufferAccess:
     meaningful extents per axis (== dims except where a wrapper padded —
     the vsmm row axis), letting the analyzer quote bytes both at the
     kernel's padded extents and at `conv_layer_traffic`'s logical ones.
-    ``unblocked`` means the index map yields element offsets
-    (`pl.Unblocked`); otherwise block indices scaled by ``block``.
+    ``element`` means the index map yields element offsets (every block
+    dim a `pl.Element`); otherwise block indices scaled by ``block``.
     """
 
     name: str
@@ -92,7 +93,7 @@ class BufferAccess:
     index_map: IndexMap
     policy: str
     itemsize: int
-    unblocked: bool = False
+    element: bool = False
     sweep_axes: tuple[int, ...] = ()
 
     def __post_init__(self) -> None:
@@ -129,6 +130,19 @@ class KernelPlan:
             if b.name == name:
                 return b
         raise KeyError(name)
+
+
+def _epilogue_buffers(nb: int, vn: int, index_map: IndexMap, *,
+                      has_scale: bool, has_bias: bool,
+                      itemsize: int) -> list[BufferAccess]:
+    """The per-cout (nb, 1, vn) scale and bias operands (excluded from the
+    byte contract, bounds still proven)."""
+    return [
+        BufferAccess(name=name, block=(1, 1, vn), dims=(nb, 1, vn),
+                     valid=(nb, 1, vn), index_map=index_map,
+                     policy="excluded", itemsize=itemsize)
+        for name, on in (("scale", has_scale), ("bias", has_bias)) if on
+    ]
 
 
 def conv_plan(
@@ -193,7 +207,8 @@ def conv_plan(
     bh = min(bh, ho)
     hop = _round_up(ho, bh)
     hb = hop // bh
-    hh = stride * (bh - 1) + (kh - 1) * dilation + 1
+    hh = halo_block_rows(kh, stride, bh, dilation)
+    phases = stride * stride
     res_bytes = n * hop * wo * cout * out_itemsize if has_residual else 0
 
     out_buf = BufferAccess(
@@ -205,19 +220,9 @@ def conv_plan(
         policy="distinct",
         itemsize=out_itemsize,
     )
-    extras: list[BufferAccess] = []
-    if has_scale:
-        extras.append(BufferAccess(
-            name="scale", block=(1, vn), dims=(nb, vn), valid=(nb, vn),
-            index_map=conv_bias_index_map(), policy="excluded",
-            itemsize=out_itemsize,
-        ))
-    if has_bias:
-        extras.append(BufferAccess(
-            name="bias", block=(1, vn), dims=(nb, vn), valid=(nb, vn),
-            index_map=conv_bias_index_map(), policy="excluded",
-            itemsize=out_itemsize,
-        ))
+    extras: list[BufferAccess] = _epilogue_buffers(
+        nb, vn, conv_bias_index_map(), has_scale=has_scale,
+        has_bias=has_bias, itemsize=out_itemsize)
     if has_residual:
         extras.append(dataclasses.replace(
             out_buf, name="residual", itemsize=out_itemsize))
@@ -235,11 +240,12 @@ def conv_plan(
             rows, bwp = halo_layout_dims(
                 h, w, kh=kh, kw=kw, stride=stride, dilation=dilation,
                 h_out=hop)
+            dims = (n, nb, phases, rows, bwp, vn)
             in_buf = BufferAccess(
-                name="input", block=(1, hh, bwp, 1, vn),
-                dims=(n, rows, bwp, nb, vn), valid=(n, rows, bwp, nb, vn),
-                index_map=dw_halo_in_index_map(hb, stride, bh),
-                policy="distinct", itemsize=itemsize, unblocked=True,
+                name="input", block=(1, 1, phases, hh, bwp, vn),
+                dims=dims, valid=dims,
+                index_map=dw_halo_in_index_map(hb, bh),
+                policy="distinct", itemsize=itemsize, element=True,
             )
             cost = dw_halo_kernel_cost(
                 n=n, hop=hop, w_out=wo, kh=kh, stride=stride, bwp=bwp, bh=bh,
@@ -295,12 +301,13 @@ def conv_plan(
             index_map=conv_weight_index_map(resident=resident),
             policy="distinct", itemsize=w_itemsize,
         )
+        dims = (n, cb, phases, rows, bwp, vk)
         if resident:
             in_buf = BufferAccess(
-                name="input", block=(1, hh, bwp, cb, vk),
-                dims=(n, rows, bwp, cb, vk), valid=(n, rows, bwp, cb, vk),
-                index_map=resident_in_index_map(hb, stride, bh),
-                policy="distinct", itemsize=itemsize, unblocked=True,
+                name="input", block=(1, cb, phases, hh, bwp, vk),
+                dims=dims, valid=dims,
+                index_map=resident_in_index_map(hb, bh),
+                policy="distinct", itemsize=itemsize, element=True,
             )
             grid = (n * hb, nb, s_steps)
             out_buf = dataclasses.replace(
@@ -316,10 +323,10 @@ def conv_plan(
             kind = "resident"
         else:
             in_buf = BufferAccess(
-                name="input", block=(1, hh, bwp, 1, vk),
-                dims=(n, rows, bwp, cb, vk), valid=(n, rows, bwp, cb, vk),
-                index_map=halo_in_index_map(hb, stride, bh, cbg, spg),
-                policy="sweep_distinct", itemsize=itemsize, unblocked=True,
+                name="input", block=(1, 1, phases, hh, bwp, vk),
+                dims=dims, valid=dims,
+                index_map=halo_in_index_map(hb, bh, cbg, spg),
+                policy="sweep_distinct", itemsize=itemsize, element=True,
                 sweep_axes=(0, 1),
             )
             grid = (nb, n * hb, s_steps)
@@ -380,8 +387,9 @@ def fc_plan(
     kb = k // vk
     res_bytes = mp * nb * vn * out_itemsize if has_residual else 0
     x_buf = BufferAccess(
-        name="input", block=(bm, vk), dims=(mp, k), valid=(m, k),
-        index_map=vsmm_x_index_map(), policy="per_step", itemsize=itemsize,
+        name="input", block=(1, bm, vk), dims=(kb, mp, vk),
+        valid=(kb, m, vk), index_map=vsmm_x_index_map(), policy="per_step",
+        itemsize=itemsize,
     )
     w_buf = BufferAccess(
         name="weights", block=(1, 1, vk, vn), dims=(nb, s_steps, vk, vn),
@@ -393,19 +401,9 @@ def fc_plan(
         valid=(m, nb * vn), index_map=vsmm_out_index_map(),
         policy="distinct", itemsize=out_itemsize,
     )
-    extras: list[BufferAccess] = []
-    if has_scale:
-        extras.append(BufferAccess(
-            name="scale", block=(1, vn), dims=(nb, vn), valid=(nb, vn),
-            index_map=vsmm_bias_index_map(), policy="excluded",
-            itemsize=out_itemsize,
-        ))
-    if has_bias:
-        extras.append(BufferAccess(
-            name="bias", block=(1, vn), dims=(nb, vn), valid=(nb, vn),
-            index_map=vsmm_bias_index_map(), policy="excluded",
-            itemsize=out_itemsize,
-        ))
+    extras = _epilogue_buffers(nb, vn, vsmm_bias_index_map(),
+                               has_scale=has_scale, has_bias=has_bias,
+                               itemsize=out_itemsize)
     if has_residual:
         extras.append(dataclasses.replace(
             out_buf, name="residual", itemsize=out_itemsize))

@@ -18,21 +18,29 @@ Input layouts — two implementations of the same math
 ----------------------------------------------------
 
 **Halo (default, `vsconv_halo_pallas`)** reads the raw SAME-padded NHWC
-input *directly*.  `build_halo_input` only pads and reshapes:
+input *directly*.  `build_halo_input` pads it and splits it into its
+``stride x stride`` phases (space-to-depth) in one XLA pass:
 
-  XH (N, rows, bW, CB, vk),  rows = stride*(Hout-1) + kh
+  XH (N, CB, stride**2, rows, bW, vk)
+  XH[n, c, py*stride + px, r, q] = pad(x)[n, stride*r + py, stride*q + px, c]
 
-(the reshape C -> (CB, vk) is free — channels are contiguous).  The
-BlockSpec carves, per output row-block of ``bh`` rows, an overlapping
-*halo block* of ``bh*stride + kh - stride`` input rows (`pl.Unblocked`
-element-offset indexing), and the tap ``(ky, kx)`` is resolved *inside*
-the kernel: row ``ky + stride*i`` and column ``kx + stride*j`` of the halo
-block feed output pixel ``(i, j)``, i.e. one dynamic slice plus a static
-strided subselect.  Because the halo offsets depend only on the row-block
-and the cin tile — not on the tap — consecutive sparse steps over the same
-cin tile *revisit* the same block and Pallas skips the DMA: with the
-stored tiles ordered cin-major (`core.vector_sparse.conv_cin_major`, the
-order `models.graph.sparse_conv_from_dense` emits), each cin tile's halo
+with rows = Hout + ((kh-1)*dilation) // stride (stride 1: one phase,
+rows = Hout + kh - 1).  The cin-tile axis sits ahead of the spatial axes
+and vk is the whole minor axis, so every block ends in (rows-window, bW,
+vk) — a layout the TPU compiler accepts.  The BlockSpec carves, per output
+row-block of ``bh`` rows, an overlapping *halo block* of
+``bh + ((kh-1)*dilation) // stride`` phase rows of every phase
+(`pl.Element` element-offset indexing), and the tap ``(ky, kx)`` is
+resolved *inside* the kernel: output pixel ``(i, j)`` reads phase
+``((ky*dilation) % stride, (kx*dilation) % stride)`` at row
+``i + (ky*dilation) // stride`` and column ``j + (kx*dilation) // stride``
+— contiguous windows, no strided subselect (which the TPU compiler lowers
+to an unsupported gather).  Because the halo offsets depend only on the
+row-block and the cin tile — not on the tap — consecutive sparse steps
+over the same cin tile *revisit* the same block and Pallas skips the DMA:
+with the stored tiles ordered cin-major (`core.vector_sparse.
+conv_cin_major`, the order `models.graph.sparse_conv_from_dense` emits),
+each cin tile's halo
 is fetched once per (strip, row-block), so input HBM traffic is ~1x the
 input plus the halo overlap — the paper's fetch-once-broadcast-everywhere
 data movement story, realized as index arithmetic.
@@ -108,7 +116,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sparse_ops import same_pads
 from repro.core.vector_sparse import VectorSparse
-from repro.kernels.vsmm import _mac_dot
+from repro.kernels.vsmm import (
+    _epilogue, _mac_dot, _nonzero, _unpack_refs, _widen, epilogue_operands,
+)
 
 __all__ = [
     "vsconv_pallas", "vsconv_halo_pallas", "vsconv_dw_halo_pallas",
@@ -118,7 +128,7 @@ __all__ = [
     "RESIDENT_MAX_H", "halo_in_index_map", "resident_in_index_map",
     "dw_halo_in_index_map", "stack_in_index_map", "dw_stack_in_index_map",
     "conv_weight_index_map", "conv_out_index_map", "conv_bias_index_map",
-    "halo_layout_dims", "stack_layout_dims",
+    "halo_layout_dims", "stack_layout_dims", "halo_block_rows",
 ]
 
 
@@ -167,22 +177,30 @@ def use_resident_halo(h_out: int, groups: int) -> bool:
     return h_out < RESIDENT_MAX_H and groups == 1
 
 
+def halo_block_rows(kh: int, stride: int, bh: int, dilation: int = 1) -> int:
+    """Phase rows in one halo block: the ``bh`` output rows plus the taps'
+    reach, ``((kh-1)*dilation) // stride`` rows, in every phase plane."""
+    return bh + ((kh - 1) * dilation) // stride
+
+
 def halo_kernel_cost(
     *, n: int, hop: int, w_out: int, kh: int, stride: int, bwp: int, bh: int,
     nb: int, s_steps: int, cb: int, vk: int, vn: int, dilation: int = 1,
     resident: bool = False, in_itemsize: int = 4, w_itemsize: int = 4,
     out_itemsize: int = 4, residual_bytes: int = 0,
 ) -> pl.CostEstimate:
-    """Kernel-side cost of the halo impl.
+    """Kernel-side cost of the halo impl (``bwp`` is the phase-plane width
+    `halo_layout_dims` gives).
 
     The halo block offset depends only on (row-block, cin tile): with the
     stored tiles cin-major per strip, consecutive taps of one cin tile
     revisit the same block (no DMA), so each of the min(S, cb) distinct cin
     tiles is fetched once per (strip, row-block) — a halo block of
-    ``stride*(bh-1) + (kh-1)*dilation + 1`` rows instead of S fetches of bh
-    rows.  ``cb`` is the cin tiles *reachable from one strip* — Cin/vk for
-    an ungrouped conv, Cin/(groups*vk) for a grouped one (a strip only ever
-    touches its own group's channels, the per-group fetch accounting).
+    ``stride**2`` phase planes x `halo_block_rows` rows instead of S
+    fetches of bh rows.  ``cb`` is the cin tiles *reachable from one
+    strip* — Cin/vk for an ungrouped conv, Cin/(groups*vk) for a grouped
+    one (a strip only ever touches its own group's channels, the per-group
+    fetch accounting).
 
     ``resident`` is the tiny-feature-map layout (`use_resident_halo`): one
     block holding *all* ``cb`` cin tiles, offset independent of both strip
@@ -190,11 +208,11 @@ def halo_kernel_cost(
     per (image, row-block), no per-strip re-fetch at all.
     """
     hb = hop // bh
-    hh = stride * (bh - 1) + (kh - 1) * dilation + 1
+    block = stride * stride * halo_block_rows(kh, stride, bh, dilation) * bwp
     if resident:
-        input_bytes = n * hb * hh * bwp * cb * vk * in_itemsize
+        input_bytes = n * hb * block * cb * vk * in_itemsize
     else:
-        input_bytes = n * hb * nb * min(s_steps, cb) * hh * bwp * vk \
+        input_bytes = n * hb * nb * min(s_steps, cb) * block * vk \
             * in_itemsize
     return pl.CostEstimate(
         flops=2 * n * hop * w_out * nb * s_steps * vk * vn,
@@ -222,11 +240,11 @@ def dw_halo_kernel_cost(
     tap).
     """
     hb = hop // bh
-    hh = stride * (bh - 1) + (kh - 1) * dilation + 1
+    block = stride * stride * halo_block_rows(kh, stride, bh, dilation) * bwp
     return pl.CostEstimate(
         flops=2 * n * hop * w_out * nb * s_steps * vc,
         bytes_accessed=(
-            n * hb * nb * hh * bwp * vc * in_itemsize
+            n * hb * nb * block * vc * in_itemsize
             + nb * s_steps * vc * w_itemsize
             + n * hop * w_out * nb * vc * out_itemsize
             + residual_bytes
@@ -274,38 +292,39 @@ def dw_stack_kernel_cost(
 # the row-block outermost; vsmm (j, mi, s).
 
 
-def halo_in_index_map(hb: int, stride: int, bh: int, cbg: int, spg: int):
-    """Streaming halo input (element offsets, `pl.Unblocked`): one image,
-    one overlapping halo row window, full width, one cin tile.  The offset
-    is tap-independent, so consecutive sparse steps on one cin tile revisit
-    the block without a new DMA; a grouped strip adds its group's base cin
-    tile."""
+def halo_in_index_map(hb: int, bh: int, cbg: int, spg: int):
+    """Streaming halo input (element offsets, `pl.Element`): one image, one
+    cin tile, every phase, one overlapping halo row window, full width.
+    The offset is tap-independent, so consecutive sparse steps on one cin
+    tile revisit the block without a new DMA; a grouped strip adds its
+    group's base cin tile."""
     def index_map(j, m, s, idx):
         return (
-            m // hb,                    # image
-            (m % hb) * stride * bh,     # halo window start row
-            0,
+            m // hb,                             # image
             (j // spg) * cbg + idx[j, s] % cbg,  # cin tile (+ group base)
+            0,                                   # every phase
+            (m % hb) * bh,                       # halo window start row
+            0,
             0,
         )
     return index_map
 
 
-def resident_in_index_map(hb: int, stride: int, bh: int):
+def resident_in_index_map(hb: int, bh: int):
     """Resident (tiny-feature-map) halo input: one block holding ALL cin
     tiles, offset a function of the row-block only — with the
     (image, row-block) grid axis outermost the block is DMA'd exactly once
     per (image, row-block)."""
     def index_map(m, j, s, idx):
-        return (m // hb, (m % hb) * stride * bh, 0, 0, 0)
+        return (m // hb, 0, 0, (m % hb) * bh, 0, 0)
     return index_map
 
 
-def dw_halo_in_index_map(hb: int, stride: int, bh: int):
+def dw_halo_in_index_map(hb: int, bh: int):
     """Depthwise halo input: strip j IS the channel tile; the offset is
     tap-independent, so the halo is fetched once per (strip, row-block)."""
     def index_map(j, m, s, idx):
-        return (m // hb, (m % hb) * stride * bh, 0, j, 0)
+        return (m // hb, j, 0, (m % hb) * bh, 0, 0)
     return index_map
 
 
@@ -365,26 +384,28 @@ def conv_out_index_map(hb: int, resident: bool = False):
 
 
 def conv_bias_index_map(resident: bool = False):
-    """Strip j's bias tile (excluded from the byte contract: one (1, vn)
+    """Strip j's (1, 1, vn) bias tile (excluded from the byte contract: one
     tile per strip, noise next to the input/weight/output terms)."""
     if resident:
         def index_map(m, j, s, idx):
-            return (j, 0)
+            return (j, 0, 0)
     else:
         def index_map(j, m, s, idx):
-            return (j, 0)
+            return (j, 0, 0)
     return index_map
 
 
 def halo_layout_dims(h: int, w: int, *, kh: int, kw: int, stride: int,
-                     dilation: int, h_out: int, sublane: int = 8
-                     ) -> tuple[int, int]:
-    """(rows, bW) of `build_halo_input`'s padded buffer for the given
-    geometry — the single source the builder, the cost model, and the
-    analyzer's bounds proof all share."""
+                     dilation: int, h_out: int) -> tuple[int, int]:
+    """(rows, bW) of one phase plane of `build_halo_input`'s buffer for the
+    given geometry — the single source the builder, the cost model, and
+    the analyzer's bounds proof all share.  A tap reaches
+    ``((k-1)*dilation) // stride`` phase rows/columns past the output
+    extent.  bW needs no rounding: the halo block spans the whole width,
+    and a block dim equal to the array's is always legal on the TPU."""
     wo, _, _ = same_pads(w, kw, stride, dilation)
-    rows = stride * (h_out - 1) + (kh - 1) * dilation + 1
-    bw = -(-(stride * (wo - 1) + (kw - 1) * dilation + 1) // sublane) * sublane
+    rows = h_out + ((kh - 1) * dilation) // stride
+    bw = wo + ((kw - 1) * dilation) // stride
     return rows, bw
 
 
@@ -410,14 +431,13 @@ def build_halo_input(
     dilation: int = 1,
     vk: int,
     h_out: int | None = None,
-    sublane: int = 8,
 ) -> jax.Array:
-    """NHWC -> (N, rows, bW, CB, vk) SAME-padded direct input for the halo
-    kernel.  One `jnp.pad` (the only HBM copy of the layout) plus a free
-    channel-split reshape; with the effective (dilated) kernel extent
-    ke = (k-1)*dilation + 1, rows = stride*(Hout-1) + ke_h so every halo
-    block and in-kernel tap slice stays in bounds, bW = stride*(Wout-1) +
-    ke_w rounded up to ``sublane``.
+    """NHWC -> (N, CB, stride**2, rows, bW, vk) SAME-padded, phase-split
+    direct input for the halo kernel: phase ``py*stride + px`` holds padded
+    pixels ``(stride*r + py, stride*q + px)``.  One pad + transpose (XLA
+    fuses them into the only HBM copy of the layout); (rows, bW) per phase
+    plane come from `halo_layout_dims`, so every halo block and in-kernel
+    tap window stays in bounds.
 
     ``h_out`` lets the caller round Hout up to a row-block multiple (the
     extra rows read zero padding).
@@ -428,12 +448,16 @@ def build_halo_input(
     _, pl_, _ = same_pads(w, kw, stride, dilation)
     ho = h_out or ho
     rows, bw = halo_layout_dims(h, w, kh=kh, kw=kw, stride=stride,
-                                dilation=dilation, h_out=ho, sublane=sublane)
+                                dilation=dilation, h_out=ho)
+    s = stride
     xp = jnp.pad(
         x,
-        ((0, 0), (pt, rows - h - pt), (pl_, bw - w - pl_), (0, 0)),
-    )
-    return xp.reshape(n, rows, bw, c // vk, vk)
+        ((0, 0), (pt, max(s * rows - h - pt, 0)),
+         (pl_, max(s * bw - w - pl_, 0)), (0, 0)),
+    )[:, :s * rows, :s * bw]
+    xp = xp.reshape(n, rows, s, bw, s, c // vk, vk)
+    return xp.transpose(0, 5, 2, 4, 1, 3, 6).reshape(
+        n, c // vk, s * s, rows, bw, vk)
 
 
 def build_row_tap_stack(
@@ -487,17 +511,29 @@ def build_row_tap_stack(
 # Halo kernel (default): direct input, tap resolved in-kernel
 # --------------------------------------------------------------------------
 
+def _tap_window(x_ref, ct, ky, kx: int, *, stride: int, dilation: int,
+                bh: int, w_out: int) -> jax.Array:
+    """The (bh, w_out, C) input window of tap (ky, kx) from a phase-split
+    halo block: phase ((ky*d) % stride, (kx*d) % stride), rows from
+    (ky*d) // stride, columns from (kx*d) // stride.  ``ky`` is dynamic (a
+    leading-axis offset); ``kx`` is static, so the column offset on the
+    sublane axis is a constant — the TPU compiler takes dynamic sublane
+    offsets for 32-bit data only.  int8 windows are widened (`_widen`)."""
+    py, r0 = (ky * dilation) % stride, (ky * dilation) // stride
+    px, c0 = (kx * dilation) % stride, (kx * dilation) // stride
+    return _widen(x_ref[0, ct, py * stride + px, pl.ds(r0, bh),
+                        pl.ds(c0, w_out), :])
+
+
 def _halo_kernel(idx_ref, xh_ref, w_ref, *refs, cb: int, kw: int, stride: int,
-                 dilation: int, bh: int, w_out: int, fuse_relu: bool,
-                 has_scale: bool, has_bias: bool, has_residual: bool,
-                 skip_zero_inputs: bool):
-    it = iter(refs)
-    scale_ref = next(it) if has_scale else None
-    bias_ref = next(it) if has_bias else None
-    res_ref = next(it) if has_residual else None
-    o_ref = next(it)
-    acc_ref = next(it)
-    j = pl.program_id(0)
+                 dilation: int, bh: int, w_out: int, resident: bool,
+                 fuse_relu: bool, has_scale: bool, has_bias: bool,
+                 has_residual: bool, skip_zero_inputs: bool):
+    scale_ref, bias_ref, res_ref, o_ref, acc_ref = _unpack_refs(
+        refs, has_scale=has_scale, has_bias=has_bias,
+        has_residual=has_residual)
+    # streaming grid (j, m, s); resident grid (m, j, s), row-block outermost
+    j = pl.program_id(1 if resident else 0)
     s = pl.program_id(2)
 
     @pl.when(s == 0)
@@ -505,112 +541,40 @@ def _halo_kernel(idx_ref, xh_ref, w_ref, *refs, cb: int, kw: int, stride: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # decode the K-tile id t = (ky*kw + kx) * cb + cin_tile (cb = cin tiles
-    # reachable from this strip — per group for a grouped conv); the cin
-    # tile is already resolved by the index_map, the whole tap resolves here
+    # reachable from this strip — per group for a grouped conv).  Streaming:
+    # the index_map already selected the cin tile.  Resident: the block
+    # holds every cin tile and it is a dynamic leading-axis index here.
     t = idx_ref[j, s]
     tap = t // cb
     ky = tap // kw
-    kx = tap % kw
+    ct = t % cb if resident else 0
+    for kx in range(kw):
+        @pl.when(tap % kw == kx)
+        def _tap(kx=kx):
+            xt = _tap_window(xh_ref, ct, ky, kx, stride=stride,
+                             dilation=dilation, bh=bh, w_out=w_out)
+            xs2 = xt.reshape(bh * w_out, xt.shape[-1])
 
-    # output pixel (i, jj) of this row block reads halo element
-    # (ky*dilation + stride*i, kx*dilation + stride*jj): dynamic tap offset
-    # + static stride
-    rlen = stride * (bh - 1) + 1
-    clen = stride * (w_out - 1) + 1
-    xt = xh_ref[0, pl.ds(ky * dilation, rlen),
-                pl.ds(kx * dilation, clen), 0]  # (rlen, clen, vk)
-    if stride > 1:
-        xt = xt[::stride, ::stride]
-    xs2 = xt.reshape(bh * w_out, xt.shape[-1])
+            def _mac():
+                acc_ref[...] += _mac_dot(xs2, w_ref[0, 0])
 
-    def _mac():
-        acc_ref[...] += _mac_dot(xs2, w_ref[0, 0])
-
-    if skip_zero_inputs:
-        # paper's input zero-vector skip (post-ReLU activations)
-        pl.when(jnp.any(xs2 != 0))(_mac)
-    else:
-        _mac()
+            if skip_zero_inputs:
+                # paper's input zero-vector skip (post-ReLU activations)
+                pl.when(_nonzero(xs2))(_mac)
+            else:
+                _mac()
 
     @pl.when(s == pl.num_programs(2) - 1)
     def _flush():
-        acc = acc_ref[...].reshape(o_ref.shape)
-        if has_scale:
-            # int8 dequant first: the accumulator holds exact int sums and
-            # the scales are powers of two, so this multiply is exact —
-            # FMA contraction with the bias add cannot change the result
-            acc = acc * scale_ref[0].astype(jnp.float32)
-        if has_bias:
-            acc = acc + bias_ref[0].astype(jnp.float32)
-        if has_residual:
-            # ResNet shortcut fused at flush: add before the ReLU so the
-            # whole basic block retires with one on-chip epilogue
-            acc = acc + res_ref[...].astype(jnp.float32)
-        if fuse_relu:
-            acc = jnp.maximum(acc, 0.0)
-        o_ref[...] = acc.astype(o_ref.dtype)
+        o_ref[...] = _epilogue(
+            acc_ref[...].reshape(o_ref.shape), scale_ref, bias_ref, res_ref,
+            fuse_relu=fuse_relu).astype(o_ref.dtype)
 
 
-def _halo_resident_kernel(idx_ref, xh_ref, w_ref, *refs, cb: int, kw: int,
-                          stride: int, dilation: int, bh: int, w_out: int,
-                          fuse_relu: bool, has_scale: bool, has_bias: bool,
-                          has_residual: bool, skip_zero_inputs: bool):
-    """Tiny-feature-map variant of `_halo_kernel`: the block holds ALL cb
-    cin tiles (offset independent of strip and sparse step; the row-block
-    axis is the outermost grid axis, so the whole thing is DMA'd once per
-    (image, row-block)) and the cin tile is resolved in-kernel alongside
-    the tap."""
-    it = iter(refs)
-    scale_ref = next(it) if has_scale else None
-    bias_ref = next(it) if has_bias else None
-    res_ref = next(it) if has_residual else None
-    o_ref = next(it)
-    acc_ref = next(it)
-    j = pl.program_id(1)
-    s = pl.program_id(2)
-
-    @pl.when(s == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # decode the K-tile id t = (ky*kw + kx) * cb + cin_tile — unlike the
-    # streaming kernel nothing is resolved by the index_map; tap AND cin
-    # tile are dynamic slices into the resident block
-    t = idx_ref[j, s]
-    tap = t // cb
-    ky = tap // kw
-    kx = tap % kw
-    ct = t % cb
-
-    rlen = stride * (bh - 1) + 1
-    clen = stride * (w_out - 1) + 1
-    xt = xh_ref[0, pl.ds(ky * dilation, rlen),
-                pl.ds(kx * dilation, clen), ct]  # (rlen, clen, vk)
-    if stride > 1:
-        xt = xt[::stride, ::stride]
-    xs2 = xt.reshape(bh * w_out, xt.shape[-1])
-
-    def _mac():
-        acc_ref[...] += _mac_dot(xs2, w_ref[0, 0])
-
-    if skip_zero_inputs:
-        pl.when(jnp.any(xs2 != 0))(_mac)
-    else:
-        _mac()
-
-    @pl.when(s == pl.num_programs(2) - 1)
-    def _flush():
-        acc = acc_ref[...].reshape(o_ref.shape)
-        if has_scale:
-            # exact multiply (po2 dequant scales) — FMA-contraction-proof
-            acc = acc * scale_ref[0].astype(jnp.float32)
-        if has_bias:
-            acc = acc + bias_ref[0].astype(jnp.float32)
-        if has_residual:
-            acc = acc + res_ref[...].astype(jnp.float32)
-        if fuse_relu:
-            acc = jnp.maximum(acc, 0.0)
-        o_ref[...] = acc.astype(o_ref.dtype)
+def _element_block(shape: tuple[int, ...], index_map) -> pl.BlockSpec:
+    """A BlockSpec whose index_map yields element offsets on every axis
+    (`pl.Element`), for the overlapping halo row windows."""
+    return pl.BlockSpec(tuple(pl.Element(d) for d in shape), index_map)
 
 
 @functools.partial(
@@ -639,29 +603,28 @@ def vsconv_halo_pallas(
     interpret: bool = False,
     out_dtype=None,
 ) -> jax.Array:
-    """Direct input xh (N, rows, bW, CB, vk) * sparse (kh*kw*CB*vk/groups,
-    Cout) -> (N, Hout, w_out, Cout), Hout = (rows - ke_h) // stride + 1
-    with ke_h = (kh-1)*dilation + 1.
+    """Direct input xh (N, CB, stride**2, rows, bW, vk) * sparse
+    (kh*kw*CB*vk/groups, Cout) -> (N, Hout, w_out, Cout), Hout = rows -
+    ((kh-1)*dilation) // stride.
 
     INT8: int8 ``xh`` + int8 ``vs.vals`` + ``scale`` (Cout,) — the combined
     per-cout dequant scale, applied at flush before the bias; each step's
-    MAC accumulates in int32 on the MXU and the output defaults to f32.
+    MAC is exact (`_mac_dot`) and the output defaults to f32.
 
-    ``xh`` is `build_halo_input`'s SAME-padded raw input; Hout must be a
-    multiple of ``bh`` (the `ops.vsconv` wrapper pads).  Each grid step sees
-    an overlapping ``stride*(bh-1) + ke_h``-row halo block (`pl.Unblocked`
-    element offsets) and slices its tap out in-kernel, so no tap-shifted
-    copy of the input ever exists in HBM.  ``groups`` shards the cin-tile
-    axis: output strip j belongs to group j // (NB/groups) and its stored
-    K-tile ids index that group's CB/groups cin tiles only (the index_map
-    adds the group's base tile).  ``bias`` (Cout,), ``residual``
-    (N, Hout, w_out, Cout) and ``fuse_relu`` run the epilogue at flush
-    time, identically to the stack kernel.
+    ``xh`` is `build_halo_input`'s SAME-padded, phase-split raw input; Hout
+    must be a multiple of ``bh`` (the `ops.vsconv` wrapper pads).  Each grid
+    step sees an overlapping `halo_block_rows`-row halo block of every
+    phase (`pl.Element` element offsets) and slices its tap out in-kernel,
+    so no tap-shifted copy of the input ever exists in HBM.  ``groups``
+    shards the cin-tile axis: output strip j belongs to group j // (NB/groups)
+    and its stored K-tile ids index that group's CB/groups cin tiles only
+    (the index_map adds the group's base tile).  ``bias`` (Cout,),
+    ``residual`` (N, Hout, w_out, Cout) and ``fuse_relu`` run the epilogue
+    at flush time, identically to the stack kernel.
     """
-    n, rows, bwp, cb, vk = xh.shape
-    ke_h = (kh - 1) * dilation + 1
-    assert (rows - ke_h) % stride == 0, (rows, kh, dilation, stride)
-    h = (rows - ke_h) // stride + 1
+    n, cb, phases, rows, bwp, vk = xh.shape
+    assert phases == stride * stride, (xh.shape, stride)
+    h = rows - ((kh - 1) * dilation) // stride
     nb, s_steps, vk_w, vn = vs.vals.shape
     assert cb % groups == 0 and nb % groups == 0, (cb, nb, groups)
     cbg = cb // groups   # cin tiles reachable from one strip
@@ -670,11 +633,9 @@ def vsconv_halo_pallas(
         vs.shape, xh.shape, kh, kw, groups)
     assert h % bh == 0, (h, bh)
     hb = h // bh
-    hh = stride * (bh - 1) + ke_h  # halo rows per output row-block
+    hh = halo_block_rows(kh, stride, bh, dilation)
     out_dtype = out_dtype or (jnp.float32 if xh.dtype == jnp.int8
                               else xh.dtype)
-    has_scale = scale is not None
-    has_bias = bias is not None
     has_residual = residual is not None
     resident = use_resident_halo(h, groups)
 
@@ -683,57 +644,27 @@ def vsconv_halo_pallas(
         # function of the row-block only — with the (image, row-block) axis
         # outermost every strip and sparse step revisits it, so the input
         # is DMA'd exactly once per (image, row-block)
-        in_specs = [
-            pl.BlockSpec(
-                (1, hh, bwp, cb, vk),
-                resident_in_index_map(hb, stride, bh),
-                indexing_mode=pl.Unblocked(),
-            ),
-            pl.BlockSpec((1, 1, vk, vn), conv_weight_index_map(resident=True)),
-        ]
-        out_map = conv_out_index_map(hb, resident=True)
-        bias_map = conv_bias_index_map(resident=True)
+        x_spec = _element_block((1, cb, phases, hh, bwp, vk),
+                                resident_in_index_map(hb, bh))
         grid = (n * hb, nb, s_steps)
-        kernel = functools.partial(
-            _halo_resident_kernel, cb=cb, kw=kw, stride=stride,
-            dilation=dilation, bh=bh, w_out=w_out, fuse_relu=fuse_relu,
-            has_scale=has_scale, has_bias=has_bias,
-            has_residual=has_residual,
-            skip_zero_inputs=skip_zero_inputs,
-        )
     else:
-        in_specs = [
-            # one image, one overlapping halo row window, full width, one
-            # cin tile — element offsets (Unblocked): row-blocks overlap by
-            # ke_h - stride rows, and the offsets are tap-independent so
-            # consecutive sparse steps on one cin tile revisit the block
-            # without a new DMA (cin-major tile order makes that the common
-            # case).  A grouped strip's tile id is relative to its group,
-            # so the group's base tile is added here.
-            pl.BlockSpec(
-                (1, hh, bwp, 1, vk),
-                halo_in_index_map(hb, stride, bh, cbg, spg),
-                indexing_mode=pl.Unblocked(),
-            ),
-            pl.BlockSpec((1, 1, vk, vn), conv_weight_index_map()),
-        ]
-        out_map = conv_out_index_map(hb)
-        bias_map = conv_bias_index_map()
+        # one image, one cin tile, every phase, one overlapping halo row
+        # window, full width: row-blocks overlap by hh - bh rows, and the
+        # offsets are tap-independent so consecutive sparse steps on one
+        # cin tile revisit the block without a new DMA (cin-major tile
+        # order makes that the common case).  A grouped strip's tile id is
+        # relative to its group, so the group's base tile is added here.
+        x_spec = _element_block((1, 1, phases, hh, bwp, vk),
+                                halo_in_index_map(hb, bh, cbg, spg))
         grid = (nb, n * hb, s_steps)
-        kernel = functools.partial(
-            _halo_kernel, cb=cbg, kw=kw, stride=stride, dilation=dilation,
-            bh=bh, w_out=w_out,
-            fuse_relu=fuse_relu, has_scale=has_scale, has_bias=has_bias,
-            has_residual=has_residual,
-            skip_zero_inputs=skip_zero_inputs,
-        )
+    out_map = conv_out_index_map(hb, resident=resident)
+    in_specs = [x_spec,
+                pl.BlockSpec((1, 1, vk, vn),
+                             conv_weight_index_map(resident=resident))]
     args = [vs.idx, xh, vs.vals]
-    if has_scale:
-        in_specs.append(pl.BlockSpec((1, vn), bias_map))
-        args.append(scale.reshape(nb, vn))
-    if has_bias:
-        in_specs.append(pl.BlockSpec((1, vn), bias_map))
-        args.append(bias.reshape(nb, vn))
+    epilogue_operands(in_specs, args, nb=nb, vn=vn,
+                      bias_map=conv_bias_index_map(resident=resident),
+                      scale=scale, bias=bias)
     if has_residual:
         assert residual.shape == (n, h, w_out, nb * vn), (
             residual.shape, (n, h, w_out, nb * vn))
@@ -748,7 +679,13 @@ def vsconv_halo_pallas(
         scratch_shapes=[pltpu.VMEM((bh * w_out, vn), jnp.float32)],
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(
+            _halo_kernel, cb=cbg, kw=kw, stride=stride,
+            dilation=dilation, bh=bh, w_out=w_out, resident=resident,
+            fuse_relu=fuse_relu, has_scale=scale is not None,
+            has_bias=bias is not None, has_residual=has_residual,
+            skip_zero_inputs=skip_zero_inputs,
+        ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, h, w_out, nb * vn), out_dtype),
         interpret=interpret,
@@ -772,12 +709,9 @@ def vsconv_halo_pallas(
 def _kernel(idx_ref, xt_ref, w_ref, *refs, cb: int, kw: int, stride: int,
             dilation: int, w_out: int, fuse_relu: bool, has_scale: bool,
             has_bias: bool, has_residual: bool, skip_zero_inputs: bool):
-    it = iter(refs)
-    scale_ref = next(it) if has_scale else None
-    bias_ref = next(it) if has_bias else None
-    res_ref = next(it) if has_residual else None
-    o_ref = next(it)
-    acc_ref = next(it)
+    scale_ref, bias_ref, res_ref, o_ref, acc_ref = _unpack_refs(
+        refs, has_scale=has_scale, has_bias=has_bias,
+        has_residual=has_residual)
     j = pl.program_id(0)
     s = pl.program_id(2)
 
@@ -802,25 +736,15 @@ def _kernel(idx_ref, xt_ref, w_ref, *refs, cb: int, kw: int, stride: int,
 
     if skip_zero_inputs:
         # paper's input zero-vector skip (post-ReLU activations)
-        pl.when(jnp.any(xs2 != 0))(_mac)
+        pl.when(_nonzero(xs2))(_mac)
     else:
         _mac()
 
     @pl.when(s == pl.num_programs(2) - 1)
     def _flush():
-        acc = acc_ref[...].reshape(o_ref.shape)
-        if has_scale:
-            # exact multiply (po2 dequant scales) — FMA-contraction-proof
-            acc = acc * scale_ref[0].astype(jnp.float32)
-        if has_bias:
-            acc = acc + bias_ref[0].astype(jnp.float32)
-        if has_residual:
-            # ResNet shortcut fused at flush: add before the ReLU so the
-            # whole basic block retires with one on-chip epilogue
-            acc = acc + res_ref[...].astype(jnp.float32)
-        if fuse_relu:
-            acc = jnp.maximum(acc, 0.0)
-        o_ref[...] = acc.astype(o_ref.dtype)
+        o_ref[...] = _epilogue(
+            acc_ref[...].reshape(o_ref.shape), scale_ref, bias_ref, res_ref,
+            fuse_relu=fuse_relu).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -889,12 +813,8 @@ def vsconv_pallas(
         pl.BlockSpec((1, 1, vk, vn), conv_weight_index_map()),
     ]
     args = [vs.idx, xt, vs.vals]
-    if has_scale:
-        in_specs.append(pl.BlockSpec((1, vn), conv_bias_index_map()))
-        args.append(scale.reshape(nb, vn))
-    if has_bias:
-        in_specs.append(pl.BlockSpec((1, vn), conv_bias_index_map()))
-        args.append(bias.reshape(nb, vn))
+    epilogue_operands(in_specs, args, nb=nb, vn=vn,
+                      bias_map=conv_bias_index_map(), scale=scale, bias=bias)
     if has_residual:
         assert residual.shape == (n, h, w_out, nb * vn), (
             residual.shape, (n, h, w_out, nb * vn))
@@ -944,32 +864,13 @@ def vsconv_pallas(
 # @pl.when — the same two-sided skip as the full kernels.
 
 
-def _dw_flush(acc_ref, o_ref, scale_ref, bias_ref, res_ref, *, fuse_relu,
-              has_scale, has_bias, has_residual):
-    acc = acc_ref[...].reshape(o_ref.shape)
-    if has_scale:
-        # int8 dequant first (the elementwise int8 MAC is f32-exact, so the
-        # accumulator already holds the exact integer sums)
-        acc = acc * scale_ref[0].astype(jnp.float32)
-    if has_bias:
-        acc = acc + bias_ref[0].astype(jnp.float32)
-    if has_residual:
-        acc = acc + res_ref[...].astype(jnp.float32)
-    if fuse_relu:
-        acc = jnp.maximum(acc, 0.0)
-    o_ref[...] = acc.astype(o_ref.dtype)
-
-
 def _dw_halo_kernel(idx_ref, xh_ref, w_ref, *refs, kw: int, stride: int,
                     dilation: int, bh: int, w_out: int, fuse_relu: bool,
                     has_scale: bool, has_bias: bool, has_residual: bool,
                     skip_zero_inputs: bool):
-    it = iter(refs)
-    scale_ref = next(it) if has_scale else None
-    bias_ref = next(it) if has_bias else None
-    res_ref = next(it) if has_residual else None
-    o_ref = next(it)
-    acc_ref = next(it)
+    scale_ref, bias_ref, res_ref, o_ref, acc_ref = _unpack_refs(
+        refs, has_scale=has_scale, has_bias=has_bias,
+        has_residual=has_residual)
     j = pl.program_id(0)
     s = pl.program_id(2)
 
@@ -982,32 +883,29 @@ def _dw_halo_kernel(idx_ref, xh_ref, w_ref, *refs, kw: int, stride: int,
     # revisits it and the halo is fetched exactly once per (strip, block).
     t = idx_ref[j, s]
     ky = t // kw
-    kx = t % kw
-    rlen = stride * (bh - 1) + 1
-    clen = stride * (w_out - 1) + 1
-    xt = xh_ref[0, pl.ds(ky * dilation, rlen),
-                pl.ds(kx * dilation, clen), 0]  # (rlen, clen, vc)
-    if stride > 1:
-        xt = xt[::stride, ::stride]
-    xs2 = xt.reshape(bh * w_out, xt.shape[-1])
+    for kx in range(kw):
+        @pl.when(t % kw == kx)
+        def _tap(kx=kx):
+            xt = _tap_window(xh_ref, 0, ky, kx, stride=stride,
+                             dilation=dilation, bh=bh, w_out=w_out)
 
-    def _mac():
-        # elementwise per-channel MAC: one tap vector scales its channels
-        # (f32-exact for int8 values too — every |v| <= 127 product is
-        # exactly representable, so no separate int32 path is needed)
-        acc_ref[...] += xs2.astype(jnp.float32) * w_ref[0, 0, 0].astype(
-            jnp.float32)
+            def _mac():
+                # elementwise per-channel MAC: one tap vector scales its
+                # channels (f32-exact for int8 values too — every
+                # |v| <= 127 product is exactly representable)
+                acc_ref[...] += xt.astype(jnp.float32) * w_ref[0, 0].astype(
+                    jnp.float32)
 
-    if skip_zero_inputs:
-        pl.when(jnp.any(xs2 != 0))(_mac)
-    else:
-        _mac()
+            if skip_zero_inputs:
+                pl.when(_nonzero(xt))(_mac)
+            else:
+                _mac()
 
     @pl.when(s == pl.num_programs(2) - 1)
     def _flush():
-        _dw_flush(acc_ref, o_ref, scale_ref, bias_ref, res_ref,
-                  fuse_relu=fuse_relu, has_scale=has_scale,
-                  has_bias=has_bias, has_residual=has_residual)
+        o_ref[...] = _epilogue(
+            acc_ref[...].reshape(o_ref.shape), scale_ref, bias_ref, res_ref,
+            fuse_relu=fuse_relu).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -1035,8 +933,8 @@ def vsconv_dw_halo_pallas(
     interpret: bool = False,
     out_dtype=None,
 ) -> jax.Array:
-    """Depthwise halo kernel: direct input xh (N, rows, bW, CB, vc) * tap
-    matrix (kh*kw, C) encoded vk=1/vn=vc -> (N, Hout, w_out, C).
+    """Depthwise halo kernel: direct input xh (N, CB, stride**2, rows, bW,
+    vc) * tap matrix (kh*kw, C) encoded vk=1/vn=vc -> (N, Hout, w_out, C).
 
     ``xh`` is `build_halo_input(x, vk=vc)`; the channel-tile axis CB = C/vc
     is the strip axis.  The halo block offset is tap-independent AND
@@ -1044,16 +942,15 @@ def vsconv_dw_halo_pallas(
     per (strip, row-block) regardless of tap order — the depthwise case is
     where the halo layout's fetch-once story is exact, not amortized.
     """
-    n, rows, bwp, cb, vc = xh.shape
-    ke_h = (kh - 1) * dilation + 1
-    assert (rows - ke_h) % stride == 0, (rows, kh, dilation, stride)
-    h = (rows - ke_h) // stride + 1
+    n, cb, phases, rows, bwp, vc = xh.shape
+    assert phases == stride * stride, (xh.shape, stride)
+    h = rows - ((kh - 1) * dilation) // stride
     nb, s_steps, vk_w, vn = vs.vals.shape
     assert vk_w == 1 and vn == vc and nb == cb, (vs.vals.shape, xh.shape)
     assert vs.shape == (kh * kw, cb * vc), (vs.shape, kh, kw, cb, vc)
     assert h % bh == 0, (h, bh)
     hb = h // bh
-    hh = stride * (bh - 1) + ke_h
+    hh = halo_block_rows(kh, stride, bh, dilation)
     out_dtype = out_dtype or (jnp.float32 if xh.dtype == jnp.int8
                               else xh.dtype)
     has_scale = scale is not None
@@ -1061,20 +958,13 @@ def vsconv_dw_halo_pallas(
     has_residual = residual is not None
 
     in_specs = [
-        pl.BlockSpec(
-            (1, hh, bwp, 1, vc),
-            dw_halo_in_index_map(hb, stride, bh),
-            indexing_mode=pl.Unblocked(),
-        ),
+        _element_block((1, 1, phases, hh, bwp, vc),
+                       dw_halo_in_index_map(hb, bh)),
         pl.BlockSpec((1, 1, 1, vc), conv_weight_index_map()),
     ]
     args = [vs.idx, xh, vs.vals]
-    if has_scale:
-        in_specs.append(pl.BlockSpec((1, vc), conv_bias_index_map()))
-        args.append(scale.reshape(nb, vc))
-    if has_bias:
-        in_specs.append(pl.BlockSpec((1, vc), conv_bias_index_map()))
-        args.append(bias.reshape(nb, vc))
+    epilogue_operands(in_specs, args, nb=nb, vn=vc,
+                      bias_map=conv_bias_index_map(), scale=scale, bias=bias)
     if has_residual:
         assert residual.shape == (n, h, w_out, nb * vc), (
             residual.shape, (n, h, w_out, nb * vc))
@@ -1086,7 +976,7 @@ def vsconv_dw_halo_pallas(
         grid=(nb, n * hb, s_steps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bh, w_out, vc), conv_out_index_map(hb)),
-        scratch_shapes=[pltpu.VMEM((bh * w_out, vc), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bh, w_out, vc), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(
@@ -1114,12 +1004,9 @@ def _dw_stack_kernel(idx_ref, xt_ref, w_ref, *refs, kw: int, stride: int,
                      dilation: int, w_out: int, fuse_relu: bool,
                      has_scale: bool, has_bias: bool, has_residual: bool,
                      skip_zero_inputs: bool):
-    it = iter(refs)
-    scale_ref = next(it) if has_scale else None
-    bias_ref = next(it) if has_bias else None
-    res_ref = next(it) if has_residual else None
-    o_ref = next(it)
-    acc_ref = next(it)
+    scale_ref, bias_ref, res_ref, o_ref, acc_ref = _unpack_refs(
+        refs, has_scale=has_scale, has_bias=has_bias,
+        has_residual=has_residual)
     j = pl.program_id(0)
     s = pl.program_id(2)
 
@@ -1141,15 +1028,15 @@ def _dw_stack_kernel(idx_ref, xt_ref, w_ref, *refs, kw: int, stride: int,
             jnp.float32)
 
     if skip_zero_inputs:
-        pl.when(jnp.any(xs2 != 0))(_mac)
+        pl.when(_nonzero(xs2))(_mac)
     else:
         _mac()
 
     @pl.when(s == pl.num_programs(2) - 1)
     def _flush():
-        _dw_flush(acc_ref, o_ref, scale_ref, bias_ref, res_ref,
-                  fuse_relu=fuse_relu, has_scale=has_scale,
-                  has_bias=has_bias, has_residual=has_residual)
+        o_ref[...] = _epilogue(
+            acc_ref[...].reshape(o_ref.shape), scale_ref, bias_ref, res_ref,
+            fuse_relu=fuse_relu).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -1205,12 +1092,8 @@ def vsconv_dw_stack_pallas(
         pl.BlockSpec((1, 1, 1, vc), conv_weight_index_map()),
     ]
     args = [vs.idx, xt, vs.vals]
-    if has_scale:
-        in_specs.append(pl.BlockSpec((1, vc), conv_bias_index_map()))
-        args.append(scale.reshape(nb, vc))
-    if has_bias:
-        in_specs.append(pl.BlockSpec((1, vc), conv_bias_index_map()))
-        args.append(bias.reshape(nb, vc))
+    epilogue_operands(in_specs, args, nb=nb, vn=vc,
+                      bias_map=conv_bias_index_map(), scale=scale, bias=bias)
     if has_residual:
         assert residual.shape == (n, h, w_out, c), (
             residual.shape, (n, h, w_out, c))

@@ -79,6 +79,7 @@ from repro.launch.scheduler import FleetScheduler, LockstepScheduler
 from repro.models import transformer as tfm
 from repro.models.layers import init_params
 from repro.parallel import sharding as shd
+from repro.utils.compile_cache import enable_compile_cache
 
 __all__ = [
     "Request", "ImageRequest", "LMBackend", "CNNBackend", "ReplicaGroup",
@@ -621,12 +622,15 @@ class CNNServer:
                          vk=cfg.vk, vn=cfg.vn)
         self.params = init_params(
             self.net.schema(), jax.random.PRNGKey(seed), jnp.float32)
-        self.sparse = None
+        # ``pruned``: the dense param tree computing the same function as
+        # ``sparse`` (BN folded, pruned, int8 dequantized) — the oracle a
+        # caller compares the served logits against
+        self.sparse = self.pruned = None
         if sparse:
             # dtype="int8" serves the compound sparsity x precision path:
             # per-cout power-of-two weight scales baked in at sparsify time,
             # activations quantized per-tensor at apply time
-            self.sparse, _ = self.net.sparsify(
+            self.sparse, self.pruned = self.net.sparsify(
                 self.params, self.density, vk=cfg.vk, vn=cfg.vn, dtype=dtype)
         image_size = cfg.image_size if cfg.fixed_image_size else None
         fleet = (replicas > 1 or shard_fc or fault_plan is not None
@@ -713,6 +717,7 @@ def main():
     ap.add_argument("--deadline-waves", type=int, default=None,
                     help="CNN fleet: per-request deadline in fleet ticks")
     args = ap.parse_args()
+    enable_compile_cache()
     if (args.arch is None) == (args.cnn is None):
         ap.error("choose exactly one of --arch (LM) or --cnn")
 

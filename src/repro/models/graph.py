@@ -48,6 +48,7 @@ the remainder strip, so every FC runs sparse.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any
 
@@ -821,24 +822,35 @@ class BatchedApply:
         return (self.net.name, id(self.params), id(self.sparse), self.key,
                 self.impl, id(self.mesh), tuple(shape))
 
-    def __call__(self, x: jax.Array) -> jax.Array:
-        k = self.cache_key(x.shape)
+    def _jitted(self, shape: tuple) -> Any:
+        k = self.cache_key(shape)
         fn = self.cache.get(k)
         if fn is None:
             net, params = self.net, self.params
             sparse, impl = self.sparse, self.impl
-            jitted = jax.jit(lambda xx: net_apply(net, params, xx,
-                                                  sparse=sparse, impl=impl))
-            if self.mesh is not None:
-                from repro.parallel import sharding as shd
-                mesh, rules = self.mesh, self.rules
-                def fn(xx: jax.Array, _j: Any = jitted) -> jax.Array:
-                    with shd.use_mesh(mesh, rules or shd.SERVE_RULES):
-                        return _j(xx)
-            else:
-                fn = jitted
+            fn = jax.jit(lambda xx: net_apply(net, params, xx,
+                                              sparse=sparse, impl=impl))
             self.cache[k] = fn
-        return fn(x)
+        return fn
+
+    def _context(self) -> Any:
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        from repro.parallel import sharding as shd
+        return shd.use_mesh(self.mesh, self.rules or shd.SERVE_RULES)
+
+    def __call__(self, x: jax.Array) -> jax.Array:
+        fn = self._jitted(x.shape)
+        with self._context():
+            return fn(x)
+
+    def lower(self, x: Any) -> jax.stages.Lowered:
+        """Ahead-of-time lowering of the executable serving ``x``'s shape
+        bucket (``x`` an array or a `jax.ShapeDtypeStruct`): ``.compile()``
+        then ``.as_text()`` shows what the device runs."""
+        fn = self._jitted(x.shape)
+        with self._context():
+            return fn.lower(x)
 
     @property
     def compiles(self) -> int:

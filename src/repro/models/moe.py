@@ -22,7 +22,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from repro.parallel import sharding as shd
@@ -185,12 +184,8 @@ def _moe_body_resident(
     if batch_axes:
         flat = tuple(batch_axes) if isinstance(batch_axes, (tuple, list)) else (batch_axes,)
         my = jnp.int32(0)
-        # jax.lax.axis_size appeared after 0.4.37; psum(1, axis) is the
-        # long-standing equivalent (constant-folded to the static size)
-        axis_size = getattr(jax.lax, "axis_size",
-                            lambda a: jax.lax.psum(1, a))
         for a in flat:
-            my = my * axis_size(a) + jax.lax.axis_index(a)
+            my = my * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         out = jax.lax.dynamic_slice_in_dim(out, my * (bl * t), bl * t, axis=0)
     return out.reshape(bl, t, d), aux
 
@@ -266,9 +261,9 @@ def moe_apply(params: dict, x: jax.Array, moe: MoEConfig, *, gated: bool,
             aux = jax.lax.pmean(aux, batch_phys)
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(x, router, wi, wo)
     return y, aux
 

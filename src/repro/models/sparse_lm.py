@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from repro.parallel import sharding as shd
@@ -173,10 +172,10 @@ def sparse_mlp_apply(params, x, cfg) -> jax.Array:
         spec(("ff", None, None, None, None), params["wo_vals"].shape),
         spec(("ff", None, None), params["wo_idx"].shape),
     )
-    y = shard_map(
+    y = jax.shard_map(
         lambda *a: _body(*a, cfg=cfg, model_axis=model_axis),
         mesh=mesh, in_specs=in_specs,
-        out_specs=PS(batch_phys, None, None), check_rep=False,
+        out_specs=PS(batch_phys, None, None), check_vma=False,
     )(x, params["wi_vals"], params["wi_idx"], params["wo_vals"],
       params["wo_idx"])
     return y

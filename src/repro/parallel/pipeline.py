@@ -21,24 +21,16 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 __all__ = ["gpipe_schedule", "pipeline_apply"]
-
-
-def _axis_size(axis) -> int:
-    """jax.lax.axis_size appeared after 0.4.37; psum(1, axis) is the
-    long-standing equivalent (constant-folded to the static axis size)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    return fn(axis) if fn is not None else jax.lax.psum(1, axis)
 
 
 def gpipe_schedule(stage_fn, stage_params, x_mb, *, axis: str):
     """Run inside shard_map. stage_params: THIS stage's params; x_mb
     (M, ...) microbatch inputs (meaningful at stage 0).  Returns (M, ...)
     outputs (meaningful at the last stage; zeros elsewhere)."""
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     sid = jax.lax.axis_index(axis)
     m = x_mb.shape[0]
     fwd = [(i, (i + 1) % p) for i in range(p)]
@@ -90,7 +82,7 @@ def pipeline_apply(mesh, stage_fn, all_stage_params, x_mb, *,
         outs = jnp.where(is_last, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, pod_axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=PS(),
-        check_rep=False,
+        check_vma=False,
     )(all_stage_params, x_mb)

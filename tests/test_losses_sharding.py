@@ -4,18 +4,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as PS
 
-from repro.launch.mesh import make_local_mesh
+from repro.launch.mesh import auto_mesh, make_local_mesh
 from repro.parallel import sharding as shd
 from repro.parallel.losses import chunked_cross_entropy, cross_entropy_dense
-
-
-def _abstract_mesh(sizes, names):
-    """AbstractMesh ctor compat: new jax takes (sizes, names), 0.4.37 takes
-    a tuple of (name, size) pairs."""
-    try:
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
 
 
 class TestChunkedCE:
@@ -67,7 +58,7 @@ class TestMeshRules:
         return make_local_mesh(data=1, model=1)
 
     def test_spec_demotes_non_divisible(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         rules = shd.TRAIN_RULES
         # 8 kv heads over 16-way model axis would not divide on the real
         # mesh; emulate with a shape check against a fake axis size via the
@@ -77,14 +68,14 @@ class TestMeshRules:
         assert spec == PS("data", "model")
 
     def test_missing_axis_filtered(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         spec = shd.spec_for(("batch",), mesh=mesh, rules=shd.TRAIN_RULES,
                             shape=(8,))
         # batch maps to ('pod','data'); 'pod' absent from this mesh
         assert spec == PS("data")
 
     def test_repeated_axis_demoted(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         spec = shd.spec_for(("heads", "ff"), mesh=mesh, rules=shd.TRAIN_RULES,
                             shape=(4, 4))
         # both want 'model'; the second claim loses
@@ -92,7 +83,7 @@ class TestMeshRules:
 
     def test_divisibility_guard(self):
         # AbstractMesh: spec_for only consults mesh.shape (no devices needed)
-        mesh = _abstract_mesh((1, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
         spec = shd.spec_for(("ff",), mesh=mesh, rules=shd.TRAIN_RULES,
                             shape=(7,))  # 7 % 2 != 0 -> replicate
         assert spec == PS(None)
@@ -101,7 +92,7 @@ class TestMeshRules:
         assert spec2 == PS("model")
 
     def test_kv_heads_demoted_on_16way_axis(self):
-        mesh = _abstract_mesh((16, 16), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
         spec = shd.spec_for(("batch", None, "kv_heads", "head_dim"),
                             mesh=mesh, rules=shd.TRAIN_RULES,
                             shape=(256, 4096, 8, 128))
@@ -112,7 +103,7 @@ class TestMeshRules:
         assert shd.logical(x, ("batch", None)) is x
 
     def test_constraint_applies_in_mesh(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         with shd.use_mesh(mesh, shd.TRAIN_RULES):
             y = shd.logical(jnp.ones((4, 4)), ("batch", "ff"))
             assert y.shape == (4, 4)

@@ -12,16 +12,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import auto_mesh
 from repro.parallel.pipeline import pipeline_apply
 
 _SUBPROC = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import auto_mesh
     from repro.parallel.pipeline import pipeline_apply
 
     P, M, D = 4, 8, 16
-    mesh = jax.make_mesh((P,), ("pod",))
+    mesh = auto_mesh((P,), ("pod",))
     rng = np.random.default_rng(0)
     ws = jnp.asarray(rng.standard_normal((P, D, D)) / D**0.5, jnp.float32)
     x = jnp.asarray(rng.standard_normal((M, 3, D)), jnp.float32)
@@ -66,7 +68,7 @@ def test_gpipe_multistage_subprocess():
 
 def test_gpipe_single_stage_degenerate():
     """P=1 pipeline == plain application (runs on the real single device)."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = auto_mesh((1,), ("pod",))
     w = jnp.ones((1, 4, 4)) * 0.1
     x = jnp.ones((3, 2, 4))
     out = pipeline_apply(mesh, lambda w_, h: h @ w_, w, x, pod_axis="pod")

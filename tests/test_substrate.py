@@ -161,17 +161,19 @@ class TestCompression:
         assert rel < 0.05
 
     def test_compressed_psum_under_shard_map(self, rng):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as PS
-        mesh = jax.make_mesh((1,), ("pod",))
+
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((1,), ("pod",))
         x = jnp.asarray(rng.standard_normal(256).astype(np.float32))
         err = jnp.zeros_like(x)
 
         def body(xl, el):
             return compressed_psum(xl, "pod", el)
 
-        y, new_err = shard_map(body, mesh=mesh, in_specs=(PS(), PS()),
-                               out_specs=(PS(), PS()), check_rep=False)(x, err)
+        y, new_err = jax.shard_map(body, mesh=mesh, in_specs=(PS(), PS()),
+                                   out_specs=(PS(), PS()),
+                                   check_vma=False)(x, err)
         rel = float(jnp.abs(y - x).max() / jnp.abs(x).max())
         assert rel < 0.1  # pod size 1: psum == dequantized identity
 

@@ -113,12 +113,7 @@ class TestVGGPipeline:
 
 
 def _xla_flops(compiled) -> float:
-    """cost_analysis() returned a one-element list in older jax (0.4.x),
-    a plain dict in newer releases."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return ca["flops"]
+    return compiled.cost_analysis()["flops"]
 
 
 class TestHloAnalyzer:

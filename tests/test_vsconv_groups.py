@@ -152,14 +152,15 @@ class TestGroupedTraffic:
                                 groups=groups, cout=co, s_steps=s, vk=vk,
                                 vn=vn, impl="halo")
         cbg = (c // vk) // groups
+        # halo phase-plane width bwp = Wout + (kw-1)//stride = 16 + 2
         est = halo_kernel_cost(
-            n=n, hop=16, w_out=16, kh=3, stride=1, bwp=24, bh=8,
+            n=n, hop=16, w_out=16, kh=3, stride=1, bwp=18, bh=8,
             nb=co // vn, s_steps=s, cb=cbg, vk=vk, vn=vn)
         assert (tr.input_bytes + tr.weight_bytes + tr.output_bytes
                 == est.bytes_accessed)
         # full-cin accounting would fetch 4x the tiles per strip
         est_full = halo_kernel_cost(
-            n=n, hop=16, w_out=16, kh=3, stride=1, bwp=24, bh=8,
+            n=n, hop=16, w_out=16, kh=3, stride=1, bwp=18, bh=8,
             nb=co // vn, s_steps=s, cb=c // vk, vk=vk, vn=vn)
         assert est.bytes_accessed < est_full.bytes_accessed
 
@@ -172,8 +173,9 @@ class TestGroupedTraffic:
         tr_h = conv_layer_traffic((n, h, h, c), kh=3, kw=3, stride=2,
                                   groups=c, cout=c, s_steps=s, vk=1, vn=vc,
                                   impl="halo")
+        # phase-plane width bwp = Wout + (kw-1)//stride = 8 + 1
         est_h = dw_halo_kernel_cost(
-            n=n, hop=8, w_out=8, kh=3, stride=2, bwp=24, bh=8, nb=c // vc,
+            n=n, hop=8, w_out=8, kh=3, stride=2, bwp=9, bh=8, nb=c // vc,
             s_steps=s, vc=vc)
         assert (tr_h.input_bytes + tr_h.weight_bytes + tr_h.output_bytes
                 == est_h.bytes_accessed)
