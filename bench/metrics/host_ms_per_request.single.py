@@ -1,0 +1,13 @@
+"""Host time per request in the traced calls, in ms: each ``serve`` call's
+``scheduler.serve`` span less the ``backend.wait`` spans inside it (where
+the host only waits for the device), summed, over the requests the calls
+carried (``scheduler.serve`` ``requests``).  From the program's spans
+(``harness.program_spans``)."""
+from harness.program_spans import SERVE, totals
+
+
+def read(ctx):
+    t = totals(ctx)
+    if not t or not t["requests"]:
+        return None
+    return (t[SERVE] - t["backend.wait"]) * 1e3 / t["requests"]

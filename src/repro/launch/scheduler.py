@@ -105,6 +105,17 @@ structured refusal (reason strings: ``queue_full``, ``invalid:*``,
 ``no_healthy_replicas``, ``partial_stream_lost``) — and control flow stays
 clock-free, so a faulty run (chaos-injected or real) is exactly
 replayable.
+
+Spans
+-----
+While a JAX profiler session is on, each ``serve`` call records
+`launch.spans`: ``scheduler.serve`` (``requests``), ``scheduler.admit``
+(admission, bucketing and sorting; ``refused``), ``scheduler.run`` (one
+lockstep run; ``replica``), ``scheduler.deliver`` (one delivery pass) and
+``backend.wave`` (one backend step, dispatch to collect; ``wave``,
+``replica``).  The clock readings of ``scheduler.run`` are the run stats'
+``start_s``/``run_s``; off a profiler session they are the only clock
+reads.
 """
 from __future__ import annotations
 
@@ -112,6 +123,7 @@ import contextlib
 import dataclasses
 import time
 
+from repro.launch import spans
 from repro.launch.faults import FAULT_TYPES, NonFiniteOutput
 
 __all__ = ["LockstepScheduler", "FleetScheduler", "RequestOutcome",
@@ -131,7 +143,8 @@ class RequestOutcome:
 
     ``status`` is ``"delivered"`` or ``"refused"``; refusals carry a
     machine-readable ``reason``.  ``wave`` is the fleet tick (or lockstep
-    delivery pass) the outcome was decided at; ``attempts`` counts
+    step) the outcome was decided at, the ``wave`` of the ``backend.wave``
+    span that computed a delivered request; ``attempts`` counts
     fault-driven re-placements the request survived before its outcome.
     """
 
@@ -151,26 +164,27 @@ def _deliver(be, state, slots, queue, emis, on_finish=None):
     ``on_finish`` (optional) is called once per retired request.
     """
     finished = backfills = emitted = 0
-    for j in range(len(slots)):
-        req = slots[j]
-        e = None if emis is None else emis[j]
-        while req is not None and e is not None:
-            done = be.append(req, e)
-            emitted += 1
-            e = None
-            if not done:
-                break
-            finished += 1
-            if on_finish is not None:
-                on_finish(req)
-            req = None
-            for qi, cand in enumerate(queue):
-                if be.can_backfill(state, cand):
-                    req = queue.pop(qi)
-                    backfills += 1
-                    state, e = be.backfill(state, j, req)
+    with spans.span("scheduler.deliver"):
+        for j in range(len(slots)):
+            req = slots[j]
+            e = None if emis is None else emis[j]
+            while req is not None and e is not None:
+                done = be.append(req, e)
+                emitted += 1
+                e = None
+                if not done:
                     break
-        slots[j] = req
+                finished += 1
+                if on_finish is not None:
+                    on_finish(req)
+                req = None
+                for qi, cand in enumerate(queue):
+                    if be.can_backfill(state, cand):
+                        req = queue.pop(qi)
+                        backfills += 1
+                        state, e = be.backfill(state, j, req)
+                        break
+            slots[j] = req
     return state, finished, backfills, emitted
 
 
@@ -221,6 +235,7 @@ class LockstepScheduler:
         self.backend = backend
         self.batch = batch
         self.max_queue = max_queue
+        self.waves = 0                 # lockstep steps since construction
         self.outcomes: dict = {}
 
     def serve(self, requests: list) -> list[dict]:
@@ -231,28 +246,36 @@ class LockstepScheduler:
         per-request terminal outcomes land in ``self.outcomes`` (and on
         each request's ``.outcome``).
         """
-        self.outcomes = {}
-        admitted = _admit(self.backend, list(requests), self.outcomes,
-                          max_queue=self.max_queue)
-        buckets: dict = {}
-        for r in admitted:
-            buckets.setdefault(self.backend.bucket_key(r), []).append(r)
-        stats = []
-        for queue in buckets.values():
-            queue.sort(key=self.backend.sort_key)
-            while queue:
-                stats.append(self.run_lockstep(queue))
-        return stats
+        requests = list(requests)
+        with spans.top("scheduler.serve", requests=len(requests)):
+            self.outcomes = {}
+            with spans.span("scheduler.admit") as admit:
+                admitted = _admit(self.backend, requests, self.outcomes,
+                                  max_queue=self.max_queue)
+                buckets: dict = {}
+                for r in admitted:
+                    buckets.setdefault(self.backend.bucket_key(r),
+                                       []).append(r)
+                for queue in buckets.values():
+                    queue.sort(key=self.backend.sort_key)
+                admit.set(refused=len(requests) - len(admitted))
+            stats = []
+            for queue in buckets.values():
+                while queue:
+                    stats.append(self.run_lockstep(queue))
+            return stats
 
     def _on_finish(self, req) -> None:
         _record(self.outcomes, req, RequestOutcome(
-            rid=getattr(req, "rid", None), status="delivered"))
+            rid=getattr(req, "rid", None), status="delivered",
+            wave=self.waves))
 
     def run_lockstep(self, queue: list) -> dict:
         """One lockstep run: admit up to ``batch`` requests, step until every
         slot retires, backfilling freed slots from ``queue`` (consumed in
         place).  Stats: steps, finished, backfills, emissions, start_s,
-        run_s, plus whatever `backend.finish` adds.
+        run_s (from the clock readings of the run's ``scheduler.run`` span),
+        plus whatever `backend.finish` adds.
         """
         be = self.backend
         assert queue, "run_lockstep needs at least one request"
@@ -262,28 +285,33 @@ class LockstepScheduler:
         steps = finished = backfills = emitted = 0
         ctx = getattr(be, "context", None)
         with (ctx() if ctx else contextlib.nullcontext()):
-            t0 = time.time()
-            state, emis = be.start(admitted, width)
-            start_s = time.time() - t0
-            t1 = time.time()
-            while True:
-                state, f, b, e = _deliver(be, state, slots, queue, emis,
-                                          self._on_finish)
-                finished += f
-                backfills += b
-                emitted += e
-                if all(s is None for s in slots):
-                    break
-                state, emis = be.step(state, slots)
-                steps += 1
-            run_s = time.time() - t1
+            t0 = time.time_ns()
+            run = spans.begin("scheduler.run", t0, replica=0)
+            with run:
+                state, emis = be.start(admitted, width)
+                t1 = time.time_ns()
+                while True:
+                    state, f, b, e = _deliver(be, state, slots, queue, emis,
+                                              self._on_finish)
+                    finished += f
+                    backfills += b
+                    emitted += e
+                    if all(s is None for s in slots):
+                        break
+                    self.waves += 1
+                    with spans.span("backend.wave", wave=self.waves,
+                                    replica=0):
+                        state, emis = be.step(state, slots)
+                    steps += 1
+            t2 = time.time_ns()
+            run.end(t2)
         out = {
             "steps": steps,
             "finished": finished,
             "backfills": backfills,
             "emissions": emitted,
-            "start_s": start_s,
-            "run_s": run_s,
+            "start_s": (t1 - t0) / 1e9,
+            "run_s": (t2 - t1) / 1e9,
         }
         out.update(be.finish(state) or {})
         return out
@@ -299,7 +327,8 @@ class _ReplicaRun:
     ``collect_and_deliver`` ticks until every slot is idle.  ``guard``
     (optional) validates each wave's emissions before delivery — it raises
     to reject the whole wave (output corruption), so corrupt emissions are
-    never appended.
+    never appended.  Its ``scheduler.run`` span lasts from construction to
+    `finish`, and is the parent of the run's waves and deliveries.
     """
 
     def __init__(self, replica: int, be, admitted: list, queue: list,
@@ -312,12 +341,15 @@ class _ReplicaRun:
         self.slots: list = admitted + [None] * (width - len(admitted))
         self.steps = self.finished = self.backfills = self.emitted = 0
         self._handle = None
+        self._wave = spans.OFF
         with self._ctx():
-            t0 = time.time()
-            self.state, emis = be.start(admitted, width)
-            self.start_s = time.time() - t0
-            self._t1 = time.time()
-            self._deliver(emis)
+            t0 = time.time_ns()
+            self._span = spans.begin("scheduler.run", t0, replica=replica)
+            with self._span:
+                self.state, emis = be.start(admitted, width)
+                self._t1 = time.time_ns()
+                self.start_s = (self._t1 - t0) / 1e9
+                self._deliver(emis)
 
     def _ctx(self):
         ctx = getattr(self.be, "context", None)
@@ -340,28 +372,37 @@ class _ReplicaRun:
         """Requests currently occupying slots (for fault re-placement)."""
         return [s for s in self.slots if s is not None]
 
-    def dispatch(self):
-        """Issue this replica's next step; backends with a dispatch/collect
-        split return without blocking on the result."""
+    def dispatch(self, wave: int):
+        """Issue this replica's step of fleet tick ``wave``; backends with a
+        dispatch/collect split return without blocking on the result."""
         fn = getattr(self.be, "dispatch", None)
-        with self._ctx():
-            if fn is not None:
-                self._handle = ("pending", fn(self.state, self.slots))
-            else:
-                self._handle = ("ready", self.be.step(self.state, self.slots))
+        with self._ctx(), self._span:
+            self._wave = spans.begin("backend.wave", wave=wave,
+                                     replica=self.replica)
+            with self._wave:
+                if fn is not None:
+                    self._handle = ("pending", fn(self.state, self.slots))
+                else:
+                    self._handle = ("ready",
+                                    self.be.step(self.state, self.slots))
         self.steps += 1
 
     def collect_and_deliver(self):
         kind, h = self._handle
         self._handle = None
-        with self._ctx():
-            if kind == "pending":
-                self.state, emis = self.be.collect(self.state, h, self.slots)
-            else:
-                self.state, emis = h
+        with self._ctx(), self._span:
+            with self._wave:
+                if kind == "pending":
+                    self.state, emis = self.be.collect(self.state, h,
+                                                       self.slots)
+                else:
+                    self.state, emis = h
+            self._wave.end()
             self._deliver(emis)
 
     def finish(self) -> dict:
+        t2 = time.time_ns()
+        self._span.end(t2)
         out = {
             "replica": self.replica,
             "steps": self.steps,
@@ -369,7 +410,7 @@ class _ReplicaRun:
             "backfills": self.backfills,
             "emissions": self.emitted,
             "start_s": self.start_s,
-            "run_s": time.time() - self._t1,
+            "run_s": (t2 - self._t1) / 1e9,
         }
         with self._ctx():
             out.update(self.be.finish(self.state) or {})
@@ -653,16 +694,23 @@ class FleetScheduler:
         Faulting replicas degrade and drain per the module docstring; the
         serve always returns — degraded service is structured refusals in
         ``self.outcomes``, not an exception."""
+        requests = list(requests)
+        with spans.top("scheduler.serve", requests=len(requests)):
+            return self._serve(requests)
+
+    def _serve(self, requests: list) -> list[dict]:
         self.outcomes = {}
         self._attempts = {}
         self._tick0 = self.waves
-        admitted = _admit(self.backends[0], list(requests), self.outcomes,
-                          max_queue=self.max_queue, wave=self.waves)
-        if not self.live_replicas():
-            for req in admitted:
-                self._refuse(req, "no_healthy_replicas")
-            return []
-        ladders = self._place(admitted)
+        with spans.span("scheduler.admit") as admit:
+            admitted = _admit(self.backends[0], requests, self.outcomes,
+                              max_queue=self.max_queue, wave=self.waves)
+            admit.set(refused=len(requests) - len(admitted))
+            if not self.live_replicas():
+                for req in admitted:
+                    self._refuse(req, "no_healthy_replicas")
+                return []
+            ladders = self._place(admitted)
         runs: list = [None] * self.replicas
         stats: list[dict] = []
         while True:
@@ -693,7 +741,7 @@ class FleetScheduler:
             faulted: list = []
             for run in active:
                 try:
-                    run.dispatch()
+                    run.dispatch(self.waves)
                 except self.fault_types as e:
                     faulted.append((run, e))
             for i, run in enumerate(runs):

@@ -73,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch import spans
 from repro.launch.faults import ChaosBackend, FaultPlan
 from repro.launch.mesh import make_local_mesh
 from repro.launch.scheduler import FleetScheduler, LockstepScheduler
@@ -437,6 +438,13 @@ class CNNBackend:
     dispatches every replica's wave before collecting any, so replicas'
     device work overlaps.  ``mesh``/``rules`` flow to `BatchedApply`'s
     sharded compile path (sharded FC heads — see `ReplicaGroup`).
+
+    While a profiler session is on, each wave records `launch.spans`:
+    ``backend.stack`` (the host batch), ``backend.put`` (its transfer),
+    ``backend.launch`` (the jitted call; ``miss`` when it compiled),
+    ``backend.wait`` and ``backend.fetch`` (the result's copy to the host),
+    and puts ``images`` (occupied slots) and ``rows`` (the batch computed)
+    on the scheduler's ``backend.wave`` span.
     """
 
     def __init__(self, net, params, *, sparse=None, impl: str = "auto",
@@ -496,15 +504,26 @@ class CNNBackend:
         # shrink a partial wave to the occupied slots (pow2 ladder): zero
         # images are no longer computed at full sparse-path cost
         nb = min(state["width"], 1 << max(len(occ) - 1, 0).bit_length())
-        x = np.zeros((nb, hb, wb, c), np.float32)
-        for i, j in enumerate(occ):
-            h, w, _ = slots[j].image.shape
-            x[i, :h, :w] = slots[j].image
-        return occ, self.apply(jnp.asarray(x))
+        spans.annotate(images=len(occ), rows=nb)
+        with spans.span("backend.stack"):
+            x = np.zeros((nb, hb, wb, c), np.float32)
+            for i, j in enumerate(occ):
+                h, w, _ = slots[j].image.shape
+                x[i, :h, :w] = slots[j].image
+        with spans.span("backend.put", bytes=x.nbytes):
+            x = jnp.asarray(x)
+        with spans.span("backend.launch") as launch:
+            compiles = self.apply.compiles
+            y = self.apply(x)
+            launch.set(miss=self.apply.compiles > compiles)
+        return occ, y
 
     def collect(self, state, handle, slots):
         occ, y_dev = handle
-        y = np.asarray(y_dev)
+        with spans.span("backend.wait"):
+            y_dev.block_until_ready()
+        with spans.span("backend.fetch"):
+            y = np.asarray(y_dev)
         emis = [None] * state["width"]
         for i, j in enumerate(occ):
             emis[j] = y[i]
@@ -667,7 +686,6 @@ class CNNServer:
         stats = self.scheduler.serve(list(requests))
         for s in stats:
             s["images"] = s.pop("emissions")
-            s["images_per_s"] = s["images"] / max(s["run_s"], 1e-9)
         return stats
 
 

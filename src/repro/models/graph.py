@@ -681,66 +681,70 @@ def net_apply(net: SparseNet, params: dict, x: jax.Array, *,
     sparse = sparse or {}
     saved: dict[str, jax.Array] = {}
     for l in net.layers:
-        if isinstance(l, Save):
-            saved[l.key] = x
-        elif isinstance(l, Conv):
-            xin = saved[l.src] if l.src else x
-            res = saved[l.residual] if l.residual else None
-            p = params[l.name]
-            if collect is not None:
-                collect.append((l.name, xin, p["w"], l.stride, l.groups,
-                                l.dilation))
-            if l.name in sparse:
-                entry = sparse[l.name]
-                spec = (entry if isinstance(entry, SparseConv)
-                        else SparseConv(entry))
-                bias = spec.bias if spec.bias is not None else p.get("b")
-                if l.bn and spec.bias is None:
-                    # a bare entry can't carry the folded scale/bias — running
-                    # it would silently drop batch-norm; demand `sparsify`'s
-                    # folded SparseConv instead of computing wrong activations
-                    raise ValueError(
-                        f"sparse entry for BN conv {l.name!r} has no folded "
-                        f"bias; build it with graph.sparsify (which folds BN "
-                        f"into the weights and bias) rather than encoding "
-                        f"raw weights")
-                y = apply_sparse_conv(xin, spec, bias=bias,
-                                      fuse_relu=l.relu, residual=res,
-                                      impl=impl)
+        # trace-time only: the layer's ops carry its name in their HLO
+        # metadata (``op_name``), which ties device ops to layers
+        with jax.named_scope(getattr(l, "name", type(l).__name__)):
+            if isinstance(l, Save):
+                saved[l.key] = x
+            elif isinstance(l, Conv):
+                xin = saved[l.src] if l.src else x
+                res = saved[l.residual] if l.residual else None
+                p = params[l.name]
+                if collect is not None:
+                    collect.append((l.name, xin, p["w"], l.stride, l.groups,
+                                    l.dilation))
+                if l.name in sparse:
+                    entry = sparse[l.name]
+                    spec = (entry if isinstance(entry, SparseConv)
+                            else SparseConv(entry))
+                    bias = spec.bias if spec.bias is not None else p.get("b")
+                    if l.bn and spec.bias is None:
+                        # a bare entry can't carry the folded scale/bias —
+                        # running it would silently drop batch-norm; demand
+                        # `sparsify`'s folded SparseConv instead of
+                        # computing wrong activations
+                        raise ValueError(
+                            f"sparse entry for BN conv {l.name!r} has no "
+                            f"folded bias; build it with graph.sparsify "
+                            f"(which folds BN into the weights and bias) "
+                            f"rather than encoding raw weights")
+                    y = apply_sparse_conv(xin, spec, bias=bias,
+                                          fuse_relu=l.relu, residual=res,
+                                          impl=impl)
+                else:
+                    y = _dense_conv(l, p, xin, res)
+                if l.dst:
+                    saved[l.dst] = y
+                else:
+                    x = y
+            elif isinstance(l, ResidualAdd):
+                y = x.astype(jnp.float32) + saved[l.key].astype(jnp.float32)
+                if l.relu:
+                    y = jnp.maximum(y, 0.0)
+                x = y.astype(x.dtype)
+            elif isinstance(l, Pool):
+                x = _pool(l, x)
+            elif isinstance(l, Flatten):
+                x = x.reshape(x.shape[0], -1)
+            elif isinstance(l, FC):
+                p = params[l.name]
+                if collect_fc is not None:
+                    collect_fc.append((l.name, x, p["w"]))
+                if l.name in sparse:
+                    entry = sparse[l.name]
+                    spec = (entry if isinstance(entry, SparseFC)
+                            else SparseFC(entry))
+                    bias = spec.bias if spec.bias is not None else p["b"]
+                    x = apply_sparse_fc(x, spec, bias=bias,
+                                        fuse_relu=l.relu, impl=impl)
+                else:
+                    y = jnp.dot(x, p["w"].astype(x.dtype),
+                                preferred_element_type=jnp.float32
+                                ).astype(x.dtype)
+                    y = y + p["b"].astype(y.dtype)
+                    x = jax.nn.relu(y) if l.relu else y
             else:
-                y = _dense_conv(l, p, xin, res)
-            if l.dst:
-                saved[l.dst] = y
-            else:
-                x = y
-        elif isinstance(l, ResidualAdd):
-            y = x.astype(jnp.float32) + saved[l.key].astype(jnp.float32)
-            if l.relu:
-                y = jnp.maximum(y, 0.0)
-            x = y.astype(x.dtype)
-        elif isinstance(l, Pool):
-            x = _pool(l, x)
-        elif isinstance(l, Flatten):
-            x = x.reshape(x.shape[0], -1)
-        elif isinstance(l, FC):
-            p = params[l.name]
-            if collect_fc is not None:
-                collect_fc.append((l.name, x, p["w"]))
-            if l.name in sparse:
-                entry = sparse[l.name]
-                spec = (entry if isinstance(entry, SparseFC)
-                        else SparseFC(entry))
-                bias = spec.bias if spec.bias is not None else p["b"]
-                x = apply_sparse_fc(x, spec, bias=bias,
-                                    fuse_relu=l.relu, impl=impl)
-            else:
-                y = jnp.dot(x, p["w"].astype(x.dtype),
-                            preferred_element_type=jnp.float32
-                            ).astype(x.dtype)
-                y = y + p["b"].astype(y.dtype)
-                x = jax.nn.relu(y) if l.relu else y
-        else:
-            raise TypeError(f"unknown layer spec: {l!r}")
+                raise TypeError(f"unknown layer spec: {l!r}")
     return x
 
 
