@@ -19,7 +19,7 @@ from repro.configs.vscnn_resnet18 import CONFIG
 from repro.core.accel_model import aggregate, network_cycle_reports
 from repro.data import SyntheticImages
 from repro.models.graph import (
-    build_resnet18, collect_conv_traffic, net_apply, sparsify,
+    build_resnet18, collect_conv_traffic, net_apply, runs_xla_conv, sparsify,
 )
 from repro.models.layers import init_params
 
@@ -40,9 +40,11 @@ def main():
     sparse, pruned = sparsify(net, params, CONFIG.weight_density,
                               vk=CONFIG.vk, vn=CONFIG.vn)
     n_conv = len(net.conv_layers())
-    print(f"sparsified {len(sparse)} layers — every conv ({n_conv}/{n_conv}, "
-          f"BN folded, residuals fused in-epilogue) + the {args.classes}-class "
-          f"head (remainder strip) run the vector-sparse path")
+    n_xla = sum(runs_xla_conv(e, args.impl) for e in sparse.values())
+    print(f"sparsified {len(sparse)} layers — {n_conv - n_xla}/{n_conv} convs "
+          f"(BN folded, residuals fused in-epilogue) + the {args.classes}-class "
+          f"head (remainder strip) run the vector-sparse path; XLA runs "
+          f"{n_xla} (the dense 3-channel stem)")
 
     data = SyntheticImages(args.batch, size=args.size)
     imgs = jnp.asarray(data.batch_at(0)["images"])

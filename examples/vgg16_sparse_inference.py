@@ -17,6 +17,7 @@ from repro.configs.vscnn_vgg16 import CONFIG
 from repro.core.accel_model import PE_4_14_3, PE_8_7_3, aggregate
 from repro.data import SyntheticImages
 from repro.models.cnn import sparsify_vgg16, vgg16_apply, vgg16_schema
+from repro.models.graph import runs_xla_conv
 from repro.models.layers import init_params
 
 
@@ -35,8 +36,10 @@ def main():
     sparse, pruned = sparsify_vgg16(params, CONFIG.weight_density,
                                     vk=CONFIG.vk, vn=CONFIG.vn)
     n_conv = sum(1 for k in sparse if k.startswith("conv"))
-    print(f"sparsified {len(sparse)} layers — every conv ({n_conv}/13, stem "
-          f"included via channel padding) + FC runs the vector-sparse path")
+    n_xla = sum(runs_xla_conv(e, args.impl) for e in sparse.values())
+    print(f"sparsified {len(sparse)} layers — {n_conv - n_xla}/13 convs + FC "
+          f"run the vector-sparse path; XLA runs {n_xla} (the dense "
+          f"3-channel stem)")
 
     data = SyntheticImages(args.batch, size=args.size)
     imgs = jnp.asarray(data.batch_at(0)["images"])

@@ -351,7 +351,9 @@ def check_contracts(nc: NetCheck, *, itemsize: int = 4,
     """Pass 2 over everything pass 1 surfaced, once per dtype contract.
 
     ``itemsize`` overrides the f32 contract's uniform itemsize (kept for
-    callers probing odd widths); the int8 pass always runs (1, 1, 4).
+    callers probing odd widths); the int8 pass always runs (1, 1, 4).  A
+    conv site whose float path is one XLA dot (``ConvSite.xla_float``) has
+    no kernel to plan under the f32 contract.
     """
     rep = Report()
     rows: list[PlanSummary] = []
@@ -360,6 +362,8 @@ def check_contracts(nc: NetCheck, *, itemsize: int = 4,
         if dt == "f32":
             a_i = w_i = o_i = itemsize
         for site in nc.conv_sites:
+            if dt == "f32" and site.xla_float:
+                continue
             rows.extend(check_conv_site(
                 site, rep=rep, itemsize=a_i, w_itemsize=w_i,
                 out_itemsize=o_i))

@@ -19,7 +19,8 @@ import dataclasses
 
 from repro.models.graph import (
     FC, Conv, ConvTileGeometry, FCTileGeometry, Flatten, Pool, ResidualAdd,
-    Save, SparseNet, conv_tile_geometry, fc_tile_geometry, strip_steps,
+    Save, SparseNet, conv_tile_geometry, fc_tile_geometry, keeps_dense,
+    strip_steps,
 )
 
 from .diagnostics import Report, VSCheckError
@@ -43,6 +44,7 @@ class ConvSite:
     geom: ConvTileGeometry
     s_steps: int
     has_residual: bool
+    xla_float: bool = False              # the float path is one XLA dot
 
 
 @dataclasses.dataclass(frozen=True)
@@ -199,10 +201,9 @@ def check_net(
                         f"produces {out}: the fused add cannot broadcast",
                         hint="insert a projection conv on the shortcut "
                              "(stride/channel match)")
-            # the prune rule sparsify applies: grouped layers always prune
-            # (per-strip == per-group quota); ungrouped small-cin stems
-            # stay dense-in-format
-            prune = True if l.groups > 1 else cin_g >= vk
+            # the prune rule sparsify applies; a layer it keeps dense runs
+            # its float path as one XLA dot, its int8 path on the kernels
+            prune = not keeps_dense(l.groups, cin_g, vk)
             s_steps = strip_steps(geom.kb, density, prune=prune)
             c_enc = l.cin + (0 if geom.depthwise or l.groups > 1
                              else geom.cin_pad)
@@ -210,7 +211,7 @@ def check_net(
                 name=l.name, path=path, x_shape=(n, h, w, c_enc), kh=l.kh,
                 kw=l.kw, stride=l.stride, groups=l.groups,
                 dilation=l.dilation, cout=l.cout, geom=geom, s_steps=s_steps,
-                has_residual=l.residual is not None,
+                has_residual=l.residual is not None, xla_float=not prune,
             ))
             if l.dst:
                 saved[l.dst] = out

@@ -4,8 +4,9 @@ with SCNN (Parashar et al.) and the structured-sparse FPGA accelerator
 
 Same pruning recipe and PE configurations as the paper's VGG-16 setup;
 BN is folded into the conv weights/bias at sparsify time and residual
-adds ride the kernels' fused epilogue, so every conv and FC layer runs
-the single sparse datapath end-to-end (`models.graph.build_resnet18`).
+adds ride the kernels' fused epilogue, so every conv and FC layer but
+the dense 3-channel stem (one XLA dot, `graph.keeps_dense`) runs the
+single sparse datapath end-to-end (`models.graph.build_resnet18`).
 """
 from __future__ import annotations
 

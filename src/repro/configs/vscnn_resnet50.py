@@ -6,8 +6,9 @@ The bottleneck block (1x1 reduce -> 3x3 -> 1x1 expand, 4x expansion) was
 already expressible in the kernel family; `models.graph.build_resnet50`
 wires it.  Same pruning recipe and PE configurations as the paper's VGG-16
 setup; BN folds into the conv weights/bias at sparsify time and residual
-adds ride the kernels' fused epilogue, so every conv and FC layer runs the
-single sparse datapath end-to-end.
+adds ride the kernels' fused epilogue, so every conv and FC layer but the
+dense 3-channel stem (one XLA dot, `graph.keeps_dense`) runs the single
+sparse datapath end-to-end.
 """
 from __future__ import annotations
 

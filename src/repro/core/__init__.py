@@ -25,6 +25,8 @@ from .sparse_ops import (
     dense_conv2d_3x3,
     im2col,
     im2col_3x3,
+    s2d_im2col,
+    s2d_weight_matrix,
     conv_weight_to_matrix,
     same_pads,
 )

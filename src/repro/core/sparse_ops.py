@@ -14,6 +14,10 @@ impl:
   'pallas-stack' — the conv kernel on the materialized row-tap stack
                    (oracle/fallback layout; ~kh*stride x the HBM traffic)
   'auto'         — pallas (halo) on TPU backends, jnp otherwise
+
+`models.graph.apply_sparse_conv` runs a dense narrow-Cin stem as one XLA
+dot over its `s2d_im2col` patches before any of these, on every impl but
+'jnp' and 'pallas-stack'.
 """
 from __future__ import annotations
 
@@ -21,11 +25,13 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .vector_sparse import VectorSparse
 
 __all__ = [
-    "vs_matmul", "im2col", "im2col_3x3", "vs_conv2d", "vs_conv2d_3x3",
+    "vs_matmul", "im2col", "im2col_3x3", "s2d_im2col", "s2d_weight_matrix",
+    "vs_conv2d", "vs_conv2d_3x3",
     "dense_conv2d", "dense_conv2d_3x3", "conv_weight_to_matrix", "same_pads",
 ]
 
@@ -165,6 +171,56 @@ def im2col(
         for kx in range(kw)
     ]
     return jnp.concatenate(cols, axis=-1)
+
+
+def _s2d_taps(k: int, stride: int, dilation: int) -> int:
+    """Block offsets per axis of a k-tap axis read through space-to-depth
+    by ``stride``: tap t lands at block t*dilation // stride."""
+    return (k - 1) * dilation // stride + 1
+
+
+def s2d_im2col(
+    x: jax.Array, *, kh: int = 3, kw: int = 3, stride: int = 1,
+    dilation: int = 1,
+) -> jax.Array:
+    """NHWC, SAME padding -> (N, Hout, Wout, KY*KX*stride²*C) patches of
+    the stride x stride space-to-depth of the padded input: the stride
+    becomes channels, so each of the KY*KX (`_s2d_taps`) block offsets is
+    one unit-stride slice.  Pair with `s2d_weight_matrix`; at stride 1
+    and dilation 1 this is `im2col`.  On a TPU a strided slice of a
+    narrow-C map costs about as much as the whole stem, the space-to-depth
+    one relayout."""
+    n, h, w, c = x.shape
+    s = stride
+    ho, pt, _ = same_pads(h, kh, s, dilation)
+    wo, pl_, _ = same_pads(w, kw, s, dilation)
+    ty, tx = _s2d_taps(kh, s, dilation), _s2d_taps(kw, s, dilation)
+    qh, qw = ho + ty - 1, wo + tx - 1
+    xp = jnp.pad(x, ((0, 0), (pt, max(s * qh - pt - h, 0)),
+                     (pl_, max(s * qw - pl_ - w, 0)), (0, 0)))
+    xs = xp[:, :s * qh, :s * qw].reshape(n, qh, s, qw, s, c)
+    xs = xs.transpose(0, 1, 3, 2, 4, 5).reshape(n, qh, qw, s * s * c)
+    return jnp.concatenate(
+        [xs[:, qy:qy + ho, qx:qx + wo] for qy in range(ty)
+         for qx in range(tx)], axis=-1)
+
+
+def s2d_weight_matrix(w: np.ndarray, *, stride: int = 1,
+                      dilation: int = 1) -> np.ndarray:
+    """(kh, kw, C, Cout) -> the (KY*KX*stride²*C, Cout) matrix that
+    `s2d_im2col` patches multiply: tap (ky, kx) sits at block
+    (ky*dilation // stride, kx*dilation // stride), phase
+    (ky*dilation % stride, kx*dilation % stride); rows no tap reaches are
+    zero."""
+    kh, kw, c, cout = w.shape
+    ty, tx = _s2d_taps(kh, stride, dilation), _s2d_taps(kw, stride, dilation)
+    m = np.zeros((ty, tx, stride, stride, c, cout), w.dtype)
+    for ky in range(kh):
+        for kx in range(kw):
+            qy, py = divmod(ky * dilation, stride)
+            qx, px = divmod(kx * dilation, stride)
+            m[qy, qx, py, px] = w[ky, kx]
+    return m.reshape(-1, cout)
 
 
 def im2col_3x3(x: jax.Array) -> jax.Array:

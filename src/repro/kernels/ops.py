@@ -123,6 +123,11 @@ def vsconv(
     kept as oracle and fallback) selects the input layout for all of them.
     ``bias`` (Cout,), ``residual`` (the output-shaped ResNet shortcut,
     added before the ReLU) and ``fuse_relu`` fuse the epilogue in-kernel.
+
+    Every geometry runs here, the padded small-Cin stem included; on the
+    served path `models.graph.apply_sparse_conv` runs a float stem that
+    `sparsify` kept dense as one XLA dot instead, so only int8 stems and the
+    oracle impls reach this function with one.
     """
     n, h, w, c = x.shape
     interpret = _interpret() if interpret is None else interpret

@@ -65,6 +65,8 @@ from repro.core import (
     vs_matmul,
     vs_conv2d,
     dense_conv2d,
+    s2d_im2col,
+    s2d_weight_matrix,
 )
 from .layers import P
 
@@ -72,8 +74,9 @@ __all__ = [
     "Conv", "FC", "Classifier", "Pool", "ResidualAdd", "Save", "Flatten",
     "SparseNet", "SparseConv", "SparseFC", "BatchedApply", "shard_sparse",
     "ConvTileGeometry", "FCTileGeometry", "conv_tile_geometry",
-    "fc_tile_geometry", "strip_steps",
-    "sparse_conv_from_dense", "apply_sparse_conv", "apply_sparse_fc",
+    "fc_tile_geometry", "keeps_dense", "strip_steps",
+    "sparse_conv_from_dense", "ORACLE_IMPLS", "runs_xla_conv",
+    "apply_sparse_conv", "apply_sparse_fc",
     "weight_scales", "quantize_weights_int8", "quantize_activations_int8",
     "net_schema", "net_apply", "sparsify", "collect_conv_traffic",
     "build_vgg16", "build_resnet18", "build_resnet34", "build_resnet50",
@@ -212,11 +215,15 @@ class SparseNet:
 class SparseConv:
     """One vector-sparse conv layer: weights + geometry.
 
-    ``cin_pad`` zero channels are appended to the input before the conv —
-    how a non-tileable Cin (e.g. the 3-channel stem) becomes a multiple of
-    the K-tile length.  The padded weight rows are zero, so the math is
-    unchanged; the padded input vectors are all-zero and the kernel's
-    input-side skip elides them at runtime.  ``groups``/``dilation`` carry
+    ``cin_pad`` zero channels are appended to the input before an encoded
+    path runs the conv — how a non-tileable Cin (e.g. the 3-channel stem)
+    becomes a multiple of the K-tile length.  The padded weight rows are
+    zero, so the math is unchanged.  ``dense_w`` (set by `sparsify` on a
+    float layer it keeps dense, see `keeps_dense`) is the folded weight
+    without the pad, laid out as `core.sparse_ops.s2d_weight_matrix`:
+    `apply_sparse_conv` then runs the layer as one XLA dot over the
+    unpadded input's `s2d_im2col` patches, and only the oracle impls read
+    ``vs``.  ``groups``/``dilation`` carry
     the grouped/dilated geometry (``groups == cin`` is depthwise: the
     encoded matrix is the (kh*kw, C) tap matrix with vk == 1).  ``bias``
     (when set) overrides the param-tree bias — this is where the BN-folded
@@ -234,6 +241,7 @@ class SparseConv:
     cin_pad: int = 0
     bias: jax.Array | None = None
     scale: jax.Array | None = None
+    dense_w: jax.Array | None = None
 
 
 @dataclasses.dataclass
@@ -358,6 +366,15 @@ def fc_tile_geometry(din: int, dout: int, *, vk: int = 32, vn: int = 128
     pad = -dout % vn_l
     return FCTileGeometry(vk=vk, vn=vn_l, pad=pad, kb=din // vk,
                           nb=(dout + pad) // vn_l)
+
+
+def keeps_dense(groups: int, cin_g: int, vk: int) -> bool:
+    """True for a conv `sparsify` leaves unpruned: ungrouped, with a Cin
+    below the requested K-tile (the 3-channel stems).  Vector pruning
+    cannot reach it, so every K-tile is stored and its float path is one
+    XLA dot (`apply_sparse_conv`).  Grouped and depthwise layers always
+    prune: their quota is per strip, i.e. per group."""
+    return groups == 1 and cin_g < vk
 
 
 def strip_steps(kb: int, density: float, *, prune: bool = True) -> int:
@@ -522,6 +539,17 @@ def sparse_conv_from_dense(
     return spec, wp_dense
 
 
+ORACLE_IMPLS = ("jnp", "pallas-stack")
+
+
+def runs_xla_conv(entry: SparseConv | VectorSparse, impl: str) -> bool:
+    """True when `apply_sparse_conv` runs ``entry`` as one XLA dot: a layer
+    `sparsify` kept dense in float (``dense_w`` set), on any impl but the
+    oracles, which keep their encoded paths."""
+    return (isinstance(entry, SparseConv) and entry.dense_w is not None
+            and impl not in ORACLE_IMPLS)
+
+
 def apply_sparse_conv(x: jax.Array, entry: SparseConv | VectorSparse, *,
                       bias: jax.Array | None = None, fuse_relu: bool = True,
                       residual: jax.Array | None = None,
@@ -532,11 +560,34 @@ def apply_sparse_conv(x: jax.Array, entry: SparseConv | VectorSparse, *,
     ``residual`` is the output-shaped shortcut added before the ReLU in the
     kernels' fused epilogue.
 
+    A dense narrow-Cin layer (`runs_xla_conv`) is the compiler's job: the
+    space-to-depth patches of the unpadded input (`s2d_im2col`, so a
+    stride costs no strided slice) times the folded dense weight, one XLA
+    dot at ``Precision.HIGHEST``, then the bias, residual and ReLU in f32
+    for XLA to fuse.  Its stored tiles hold nothing to skip, and the
+    kernel would contract 8-wide K-tiles of 3 real channels, one grid step
+    per tap.  A dot, not `lax.conv_general_dilated`: the TPU compiler
+    takes 20-30 s over a 3-channel conv at ``HIGHEST``, a few over this
+    dot, and every new set of weights compiles again.
+
     An int8 entry (``spec.scale`` set) quantizes the layer input per-tensor
     first; the kernel accumulates int8 x int8 in int32 and the combined
     scale ``sx * s_w`` dequantizes in the fused epilogue (before bias).
     """
     spec = entry if isinstance(entry, SparseConv) else SparseConv(entry)
+    if runs_xla_conv(spec, impl):
+        patches = s2d_im2col(x, kh=spec.kh, kw=spec.kw, stride=spec.stride,
+                             dilation=spec.dilation)
+        y = jnp.dot(patches, spec.dense_w,
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+        if bias is not None:
+            y = y + bias.astype(jnp.float32)
+        if residual is not None:
+            y = y + residual.astype(jnp.float32)
+        if fuse_relu:
+            y = jnp.maximum(y, 0.0)
+        return y.astype(x.dtype)
     scale = spec.scale
     if scale is not None:
         x, sx = quantize_activations_int8(x)
@@ -671,7 +722,8 @@ def net_apply(net: SparseNet, params: dict, x: jax.Array, *,
     sparse: {layer_name: SparseConv | SparseFC | VectorSparse} — layers
     present run the paper's vector-sparse path (weight-side structural skip
     + input-side skip, bias + residual + ReLU fused into the kernel
-    epilogue); absent layers run dense.  ``collect`` (a list) records
+    epilogue), the dense narrow-Cin stems one XLA dot (`runs_xla_conv`);
+    absent layers run dense.  ``collect`` (a list) records
     (name, layer input NHWC, weight, stride) per conv for the accelerator
     cycle model; ``collect_fc`` (a separate list, so the conv record's
     shape stays stable for its consumers) records (name, layer input,
@@ -802,6 +854,10 @@ class BatchedApply:
     one *shared* ``cache`` dict can hold several sparsified nets side by
     side.  By default each instance gets its own cache.
 
+    ``xla_convs`` is the number of conv layers each executable runs
+    through XLA instead of a kernel (`runs_xla_conv`), fixed when
+    the instance is built: the same for every shape bucket.
+
     Sharded compile path: when ``mesh`` (+ ``rules``) is set, tracing and
     execution run inside ``sharding.use_mesh(mesh, rules)`` and the cache
     key includes the mesh, so a weight tree whose leaves carry
@@ -819,6 +875,11 @@ class BatchedApply:
     cache: dict = dataclasses.field(default_factory=dict)
     mesh: object = None
     rules: object = None
+    xla_convs: int = dataclasses.field(init=False)
+
+    def __post_init__(self) -> None:
+        self.xla_convs = sum(runs_xla_conv(e, self.impl)
+                             for e in (self.sparse or {}).values())
 
     def cache_key(self, shape: tuple) -> tuple:
         # id() is stable and unique here: self (and every cached closure)
@@ -874,7 +935,8 @@ def shard_sparse(sparse: dict, *, ctx: Any = None) -> dict:
     shards the cheap wide FC heads; convs scale across replicas instead) —
     map ``conv`` to a mesh axis to cout-shard them the same way.  Strip
     counts that don't divide the mesh axis demote to replicated
-    (`sharding.spec_for`), so odd heads degrade gracefully.
+    (`sharding.spec_for`), so odd heads degrade gracefully.  Biases,
+    scales and a stem's dense weight are replicated.
     """
     from repro.parallel import sharding as shd
 
@@ -884,6 +946,9 @@ def shard_sparse(sparse: dict, *, ctx: Any = None) -> dict:
     def place(arr: jax.Array, axes: tuple) -> jax.Array:
         s = shd.named_sharding(axes, shape=arr.shape, ctx=ctx)
         return jax.device_put(arr, s)
+
+    def replicate(arr: jax.Array | None) -> jax.Array | None:
+        return None if arr is None else place(arr, (None,) * arr.ndim)
 
     def place_vs(vs: VectorSparse, axis: str) -> VectorSparse:
         return VectorSparse(
@@ -896,17 +961,12 @@ def shard_sparse(sparse: dict, *, ctx: Any = None) -> dict:
         if isinstance(entry, SparseFC):
             out[name] = dataclasses.replace(
                 entry, vs=place_vs(entry.vs, "ff"),
-                bias=None if entry.bias is None
-                else place(entry.bias, (None,)),
-                scale=None if entry.scale is None
-                else place(entry.scale, (None,)))
+                bias=replicate(entry.bias), scale=replicate(entry.scale))
         elif isinstance(entry, SparseConv):
             out[name] = dataclasses.replace(
                 entry, vs=place_vs(entry.vs, "conv"),
-                bias=None if entry.bias is None
-                else place(entry.bias, (None,)),
-                scale=None if entry.scale is None
-                else place(entry.scale, (None,)))
+                bias=replicate(entry.bias), scale=replicate(entry.scale),
+                dense_w=replicate(entry.dense_w))
         else:  # bare VectorSparse entry (FC-style)
             out[name] = place_vs(entry, "ff")
     return out
@@ -934,11 +994,14 @@ def sparsify(net: SparseNet, params: dict, density: float, *,
     Returns ``(sparse, pruned)``:
 
     * ``sparse`` — {layer name: SparseConv | SparseFC} for `net_apply`.
-      Every conv runs the sparse datapath — BN is folded into the weights
-      and a bias *before* pruning (so pruning scores see the true inference
+      Every conv is encoded — BN is folded into the weights and a bias
+      *before* pruning (so pruning scores see the true inference
       magnitudes), small-Cin stems keep their weights (density 1, standard
-      pruning practice) with input channels zero-padded to a tileable K,
-      and non-tileable FC heads get a zero-padded remainder strip.
+      pruning practice, `keeps_dense`) with input channels zero-padded to
+      a tileable K, and non-tileable FC heads get a zero-padded remainder
+      strip.  A float stem also keeps its folded dense weight
+      (``SparseConv.dense_w``): it runs as one XLA dot, every other layer
+      on the sparse kernels.
     * ``pruned`` — a dense param tree computing the identical function
       (folded weights + bias; BN entries replaced by a plain bias), the
       oracle for parity tests.
@@ -965,9 +1028,7 @@ def sparsify(net: SparseNet, params: dict, density: float, *,
                 b = np.asarray(p["b"], np.float32)
             else:
                 b = np.zeros((w.shape[3],), np.float32)
-            # grouped/depthwise layers always prune (their quota is per
-            # strip, i.e. per group); ungrouped small-Cin stems stay dense
-            prune = True if l.groups > 1 else cin_g >= vk
+            prune = not keeps_dense(l.groups, cin_g, vk)
             spec, wp = sparse_conv_from_dense(
                 w, density, vk=vk, vn=vn, stride=l.stride, groups=l.groups,
                 dilation=l.dilation, prune=prune,
@@ -978,6 +1039,9 @@ def sparsify(net: SparseNet, params: dict, density: float, *,
             sparse[l.name] = spec
             pruned[l.name] = {"w": jnp.asarray(wp, wdt),
                               "b": jnp.asarray(b, wdt)}
+            if not prune and not int8:
+                spec.dense_w = jnp.asarray(s2d_weight_matrix(
+                    wp, stride=l.stride, dilation=l.dilation), wdt)
         elif isinstance(l, FC) and include_fc:
             p = params[l.name]
             wdt = p["w"].dtype

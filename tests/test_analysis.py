@@ -127,11 +127,16 @@ class TestContracts:
         # equality with the kernel CostEstimate (VSC202), traffic-model
         # agreement (VSC203), elision soundness (VSC204), FLOPs (VSC205)
         assert not rep.errors, rep.render()
-        # halo + stack variants, each proved under both dtype contracts
-        assert len(rows) == 4
+        # halo + stack variants, each proved under both dtype contracts —
+        # but a layer sparsify keeps dense (ungrouped, cin below vk) runs
+        # its float path as one XLA dot: no f32 kernel plan
+        site = nc.conv_sites[0]
+        assert site.xla_float == (groups == 1 and cin < 32)
+        tags = (":int8",) if site.xla_float else ("", ":int8")
+        assert len(rows) == 2 * len(tags)
         assert sorted(r.path for r in rows) == sorted(
-            f"{nc.conv_sites[0].path}[{impl}{tag}]"
-            for impl in ("halo", "stack") for tag in ("", ":int8"))
+            f"{site.path}[{impl}{tag}]"
+            for impl in ("halo", "stack") for tag in tags)
 
     @given(conv_geometries())
     @settings(max_examples=15, deadline=None)
