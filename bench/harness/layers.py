@@ -2,10 +2,12 @@
 
 A network is a flat list of dicts, each with ``op`` and its fields:
 
-  conv     name, cin, cout, k, stride, bn, relu, residual, src, dst
+  conv     name, cin, cout, k, stride, bn, relu, residual, src, dst, groups
            (k x k, SAME padding; ``residual`` names a saved tensor added
            before the ReLU; ``src``/``dst`` read the input from / write the
-           output to a saved slot instead of the stream)
+           output to a saved slot instead of the stream; ``groups`` is 1,
+           or ``cin == cout`` for a depthwise conv, one k x k filter per
+           channel; other groupings are refused)
   pool     kind ('max' | 'gap'), size, stride, padding ('SAME' | 'VALID')
   save     key
   flatten
@@ -19,9 +21,11 @@ from __future__ import annotations
 
 def conv(name: str, cin: int, cout: int, k: int, stride: int = 1, *,
          bn: bool = False, relu: bool = True, residual: str | None = None,
-         src: str | None = None, dst: str | None = None) -> dict:
+         src: str | None = None, dst: str | None = None,
+         groups: int = 1) -> dict:
     return dict(op="conv", name=name, cin=cin, cout=cout, k=k, stride=stride,
-                bn=bn, relu=relu, residual=residual, src=src, dst=dst)
+                bn=bn, relu=relu, residual=residual, src=src, dst=dst,
+                groups=groups)
 
 
 def fc(name: str, din: int, dout: int, relu: bool = True) -> dict:

@@ -4,10 +4,12 @@ without any of the program's code.
 What the configuration states, and what this module computes from it:
 
 * weights: every conv / fc weight is ``fan_in**-0.5 * normal(k)`` with
-  ``k = fold_in(PRNGKey(seed), crc32("['<layer>']['w']"))`` in float32; fan-in
-  is ``k*k*cin`` for a conv and ``din`` for an fc; biases are zero; batch
-  norm is the identity at inference (scale 1, offset 0, mean 0, var 1,
-  eps 1e-5).  These are the served model's seeded weights, drawn again.
+  ``k = fold_in(PRNGKey(seed), crc32("['<layer>']['w']"))`` in float32, of
+  shape (k, k, cin/groups, cout) for a conv and (din, dout) for an fc;
+  fan-in is ``k*k*cin/groups`` for a conv and ``din`` for an fc; biases are
+  zero; batch norm is the identity at inference (scale 1, offset 0, mean 0,
+  var 1, eps 1e-5).  These are the served model's seeded weights, drawn
+  again.
 * batch norm folds into the conv weight and a bias before pruning.
 * vector pruning, per layer, in float64 scores: the weight as a
   (k*k*cin, cout) matrix (rows ordered ky, kx, cin) cut into (vk, vn) tiles,
@@ -16,8 +18,15 @@ What the configuration states, and what this module computes from it:
   (the stem).  An fc pads its output to a multiple of vn with zero columns
   before scoring (the remainder strip).  Output strips are the largest
   divisor of cout up to vn.
-* the forward pass: SAME-padded convs, ReLU, residual adds before the ReLU,
-  max pools, a global average pool, fc layers, all in float32.
+* depthwise pruning (groups == cin == cout): the weight as a (k*k, C) tap
+  matrix (rows ordered ky, kx) cut into (1, vn) tiles: each strip of vn
+  channels keeps its ``max(1, round(k*k*density))`` taps of largest L2
+  norm over the strip.  Strips are the largest divisor of C up to vn.  A
+  grouped conv with 1 < groups < cin is refused.
+* ``round`` is Python's, which rounds halves to even: round(4.5) is 4.
+* the forward pass: SAME-padded convs (depthwise ones grouped per channel),
+  ReLU, residual adds before the ReLU, max pools, a global average pool, fc
+  layers, all in float32.
 
 ``precision="highest"`` computes every conv and matmul at full float32 (the
 oracle).  ``precision="bf16x3"`` splits each operand into a bfloat16 high and
@@ -83,6 +92,16 @@ def _prune(wm: np.ndarray, density: float, vk: int, vn: int) -> np.ndarray:
     return (wm * full).astype(np.float32)
 
 
+def _groups(layer: dict) -> int:
+    """A conv's groups: 1, or cin (== cout) for a depthwise conv."""
+    g = layer["groups"]
+    if g != 1 and not g == layer["cin"] == layer["cout"]:
+        raise ValueError(f"{layer['name']}: groups {g} with cin "
+                         f"{layer['cin']}, cout {layer['cout']}: only "
+                         f"ungrouped and depthwise convs are supported")
+    return g
+
+
 def pruned_weights(layers: list[dict], config: dict, seed: int) -> dict:
     """{layer: (weight, bias)} as the served model computes with them:
     seeded, BN folded, vector-pruned (``config['sparse']``), float32 on the
@@ -92,13 +111,18 @@ def pruned_weights(layers: list[dict], config: dict, seed: int) -> dict:
     for l in layers:
         if l["op"] == "conv":
             k, cin, cout = l["k"], l["cin"], l["cout"]
-            w = _normal(seed, l["name"], (k, k, cin, cout), k * k * cin)
+            cin_g = cin // _groups(l)
+            w = _normal(seed, l["name"], (k, k, cin_g, cout), k * k * cin_g)
             b = np.zeros((cout,), np.float32)
             if l["bn"]:
                 # identity BN: scale 1, var 1 -> per-cout factor g, bias 0
                 ones = np.ones((cout,), np.float32)
                 w = w * (ones / np.sqrt(ones + BN_EPS))
-            if config["sparse"] and cin >= vk:
+            if config["sparse"] and l["groups"] > 1:
+                wm = _prune(w.reshape(k * k, cout), density, 1,
+                            _largest_divisor(cout, vn))
+                w = wm.reshape(k, k, 1, cout)
+            elif config["sparse"] and cin >= vk:
                 if cin % vk:
                     raise ValueError(f"{l['name']}: pruned conv with cin "
                                      f"{cin} not a multiple of vk {vk}")
@@ -139,11 +163,12 @@ def _product(op, a, b, precision: str):
             + (op(ah, bl, dflt, jnp.float32) + op(al, bh, dflt, jnp.float32)))
 
 
-def _conv_op(stride):
+def _conv_op(stride, groups):
     def op(x, w, precision, out_dtype):
         return jax.lax.conv_general_dilated(
             x, w, (stride, stride), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=precision,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            feature_group_count=groups, precision=precision,
             preferred_element_type=out_dtype)
     return op
 
@@ -163,7 +188,8 @@ def forward(layers: list[dict], weights: dict, x, precision: str):
         elif op == "conv":
             w, b = weights[l["name"]]
             xin = saved[l["src"]] if l["src"] else x
-            y = _product(_conv_op(l["stride"]), xin, w, precision) + b
+            y = _product(_conv_op(l["stride"], _groups(l)), xin, w,
+                         precision) + b
             if l["residual"]:
                 y = y + saved[l["residual"]]
             if l["relu"]:

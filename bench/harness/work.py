@@ -6,10 +6,12 @@ multiply-adds the vector-sparse model must do, not the dense network's.
 Kept weights follow the pruning rule of ``harness.reference``: a pruned
 layer keeps ``round(kb * density)`` (vk, vn) tiles in each output strip;
 a conv whose cin is below vk keeps all its weights; an fc's remainder strip
-counts only its real columns.  Minimum bytes per wave are the kept weights
-once, plus each image's layer input, output and residual once.  Pools,
-pads and layout copies are not counted: they are not work the network
-needs.
+counts only its real columns; a depthwise conv (groups == cin == cout)
+keeps ``round(k*k * density)`` of its k*k taps in every channel.  A
+grouped conv with 1 < groups < cin is refused.  Minimum bytes per wave
+are the kept weights once, plus each image's layer input, output and
+residual once.  Pools, pads and layout copies are not counted: they are
+not work the network needs.
 """
 from __future__ import annotations
 
@@ -71,8 +73,17 @@ def network_work(layers: list[dict], config: dict) -> list[LayerWork]:
             cout = l["cout"]
             if cout % _largest_divisor(cout, vn):
                 raise ValueError(f"{l['name']}: cout {cout} does not tile")
-            kept = _kept(k * k * ci, cout, vk, vn, density,
-                         sparse and ci >= vk)
+            if l["groups"] == 1:
+                kept = _kept(k * k * ci, cout, vk, vn, density,
+                             sparse and ci >= vk)
+            elif l["groups"] == ci == cout:
+                # the (k*k, C) tap matrix in (1, vn) tiles
+                kept = _kept(k * k, cout, 1, vn, density, sparse)
+            else:
+                raise ValueError(f"{l['name']}: groups {l['groups']} with "
+                                 f"{ci} input and {cout} output channels: "
+                                 f"only ungrouped and depthwise convs are "
+                                 f"counted")
             # a 1x1 strided conv needs only the pixels it samples
             pin = ho * wo if k == 1 else hi * wi
             act = pin * ci + ho * wo * cout * (2 if l["residual"] else 1)

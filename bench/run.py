@@ -143,7 +143,9 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool,
         spans = driver.spans
         del driver, server
         gc.collect()
-        summary = _read_trace(f"{tmp}/window", spans, record, device) \
+        layer_work = work.network_work(network(config), config)
+        summary = _read_trace(f"{tmp}/window", spans, record, device,
+                              {lw.name for lw in layer_work}) \
             if trace else None
 
     from harness.latency import percentile_ms
@@ -153,10 +155,18 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool,
     checks = _correctness(config, traffic, record, seed, control)
     print(f"after the window (reference): {counter.take()}", flush=True)
     ctx = Context(record=record, setup_s=setup_s, summary=summary,
-                  work=work.network_work(network(config), config),
+                  work=layer_work,
                   peaks=lambda: work.load_peaks(device["kind"],
                                                 config["dtype"]),
                   traffic=traffic)
+    if ctx.traced:
+        ran = summary.pallas_layers() or set()
+        least = [work.roofline_seconds(lws, ctx.traced_waves(), *ctx.peaks())
+                 for lws in ([lw for lw in layer_work if lw.name in ran],
+                             layer_work)]
+        print(f"Pallas device time {summary.pallas_ns / 1e9!r} s; roofline "
+              f"of the layers it ran {least[0]!r} s, of every layer "
+              f"{least[1]!r} s", flush=True)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = metric_reader(m["name"])(ctx)
@@ -192,18 +202,26 @@ def _start_trace(jax, tmp: str, driver) -> None:
     jax.profiler.start_trace(f"{tmp}/window", profiler_options=opts)
 
 
-def _read_trace(log_dir: str, spans: list, record, device: dict):
+def _read_trace(log_dir: str, spans: list, record, device: dict,
+                layer_names: set):
     """Reduce the window's trace; the harness spans move onto its clock."""
     from harness import trace as tr
 
     ops, t0 = tr.load_trace(log_dir)
     spans = [(n, s - t0, e - t0) for n, s, e in spans]
     window = next((s, e) for n, s, e in spans if n == "window")
-    summary = tr.reduce(ops, spans, tr.covered(ops, spans, window))
+    summary = tr.reduce(ops, spans, tr.covered(ops, spans, window),
+                        layer_names=layer_names)
     device["busy_s"] = summary.busy_ns / 1e9
     device["window_s"] = summary.window_ns / 1e9
+    ran = summary.pallas_layers()
+    scopes = ("a Pallas kernel ran outside every layer scope" if ran is None
+              else f"Pallas kernels ran under {len(ran)} of "
+                   f"{len(layer_names)} layer scopes, under none of "
+                   f"{sorted(layer_names - ran)}")
     print(f"trace: {len(ops)} device ops; {summary.window_ns / 1e9:.3f} s "
-          f"of the {record.elapsed:.3f} s window traced", flush=True)
+          f"of the {record.elapsed:.3f} s window traced; {scopes}",
+          flush=True)
     return summary
 
 

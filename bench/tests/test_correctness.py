@@ -41,9 +41,34 @@ def program_logits(config: dict, images: np.ndarray) -> np.ndarray:
     return np.stack([r.logits for r in reqs])
 
 
-@pytest.mark.parametrize("name", ["resnet50-f32", "vgg16-f32"])
+# MobileNetV1 has no configuration file yet: its 13 depthwise convs are
+# held to the limit of the f32 ResNet-50 configuration
+MOBILENET = dict(name="mobilenet-v1-f32", arch="vscnn-mobilenet-v1",
+                 reference="mobilenet_v1", weight_density=0.5, vk=32, vn=128,
+                 sparse=True, dtype="float32", limits={"logit_gap": 3e-6},
+                 **SMALL)
+
+
+def test_reference_prunes_depthwise_taps_per_channel_strip():
+    from harness.reference import network, pruned_weights
+
+    layers = network(MOBILENET)
+    weights = pruned_weights(layers, MOBILENET, SEED)
+    for l in layers:
+        if l["op"] == "conv" and l["groups"] > 1:
+            w = weights[l["name"]][0]
+            c = l["cin"]
+            assert w.shape == (3, 3, 1, c)
+            # one strip of min(C, 128) channels shares its 4 kept taps
+            kept = (w.reshape(9, c) != 0).reshape(9, -1, min(c, 128))
+            assert (kept.all(axis=2) == kept.any(axis=2)).all()
+            assert (kept.any(axis=2).sum(axis=0) == 4).all()
+
+
+@pytest.mark.parametrize("name", ["resnet50-f32", "vgg16-f32",
+                                  "mobilenet_v1"])
 def test_reference_matches_program_and_control_fails(name):
-    config = small_config(name)
+    config = MOBILENET if name == "mobilenet_v1" else small_config(name)
     images = np.random.default_rng(0).standard_normal(
         (6, 32, 32, 3)).astype(np.float32)
     ref = Reference(config, SEED, block=4)

@@ -13,6 +13,12 @@ from harness import trace as tr
 DATA = pathlib.Path(__file__).parent / "data" / "trace_resnet50_width1.json"
 
 
+# One width-1 ResNet-50 call of the singlestream cell recorded on a v5e
+# with each op's ``tf_op`` name scope stack, after the stem moved to XLA.
+SCOPES = pathlib.Path(__file__).parent / "data" / \
+    "trace_resnet50_width1_scopes.json"
+
+
 @pytest.fixture(scope="module")
 def chip_trace():
     d = json.loads(DATA.read_text())
@@ -100,3 +106,121 @@ def test_load_trace_from_a_cpu_trace(tmp_path):
     ops, start = tr.load_trace(tmp_path)
     assert ops == []  # no TPU plane on a CPU
     assert abs(start - t) < 5e9  # the profile's start on the wall clock
+
+
+PALLAS = ('%k.{i} = f32[1] custom-call(), '
+          'custom_call_target="tpu_custom_call"')
+
+
+def test_layer_of():
+    names = {"conv1", "layer1_0_conv2", "fc"}
+    assert tr.layer_of("jit(apply)/layer1_0_conv2/jit(_pad)/pad:",
+                       names) == "layer1_0_conv2"
+    assert tr.layer_of("jit(apply)/conv1/dot_general:", names) == "conv1"
+    assert tr.layer_of("jit(apply)/Pool/reduce_window_max:", names) is None
+    assert tr.layer_of("", names) is None
+
+
+def test_time_per_layer_scope():
+    names = {"a", "b", "c"}
+    ops = [(PALLAS.format(i=1), 0, 10, "jit(f)/a/pallas_call:"),
+           ("%copy.2 = f32[1] copy()", 10, 5, "jit(f)/a/transpose:"),
+           (PALLAS.format(i=3), 15, 20, "jit(f)/b/pallas_call:"),
+           ("%fusion.4 = f32[1] fusion()", 35, 7, "jit(f)/c/dot_general:"),
+           ("%copy.5 = f32[1] copy()", 42, 3, "")]
+    s = tr.reduce(ops, [], (0, 100), layer_names=names)
+    assert s.layers["a"].pallas_ns == 10 and s.layers["a"].other_ns == 5
+    assert s.layers["a"].kernels == {"k"}
+    assert s.layers["b"].pallas_ns == 20 and s.layers["b"].other_ns == 0
+    assert s.layers["c"].kernels == set() and s.layers["c"].other_ns == 7
+    assert s.layers[None].other_ns == 3
+    assert s.pallas_layers() == {"a", "b"}
+    # a kernel outside every layer's scope: its time belongs to no layer
+    s = tr.reduce(ops + [(PALLAS.format(i=6), 50, 4, "jit(f)/g:")], [],
+                  (0, 100), layer_names=names)
+    assert s.pallas_layers() is None
+    # ops without a tf_op (the older recording) carry no scope
+    s = tr.reduce([op[:3] for op in ops], [], (0, 100), layer_names=names)
+    assert s.pallas_layers() is None and s.pallas_ns == 30
+
+
+@pytest.fixture(scope="module")
+def scoped_call():
+    """(ops, spans, window, ResNet-50's layer work at 224 px, f32)."""
+    from harness import work
+    from harness.reference import network
+    from harness.spec import load_config
+
+    d = json.loads(SCOPES.read_text())
+    spans = [tuple(s) for s in d["spans"]]
+    window = next((s, e) for n, s, e in spans if n == "window")
+    config = load_config("resnet50-f32")
+    return ([tuple(o) for o in d["ops"]], spans, window,
+            work.network_work(network(config), config))
+
+
+def _roofline_ctx(summary, layer_work):
+    from types import SimpleNamespace
+
+    from harness import work
+
+    return SimpleNamespace(
+        traced=True, summary=summary, work=layer_work,
+        traced_waves=lambda: [1],
+        peaks=lambda: work.load_peaks("TPU v5 lite", "float32"))
+
+
+def test_recorded_call_layer_scopes(scoped_call):
+    ops, spans, window, layer_work = scoped_call
+    names = {lw.name for lw in layer_work}
+    assert len(names) == 54  # 53 convs and the fc
+    s = tr.reduce(ops, spans, window, layer_names=names)
+    assert s.pallas_events == 53
+    # the dense stem runs through XLA: its scope holds device time but no
+    # Pallas op; every other layer's scope holds one kernel
+    assert s.layers["conv1"].kernels == set()
+    assert s.layers["conv1"].other_ns > 0
+    assert s.pallas_layers() == names - {"conv1"}
+    assert all(len(s.layers[n].kernels) == 1 for n in names - {"conv1"})
+    assert s.layers["layer1_0_conv2"].kernels == {"vsconv_halo_pallas"}
+    assert s.layers["fc"].kernels == {"vsmm_pallas"}
+    assert sum(lt.pallas_ns for lt in s.layers.values()) == s.pallas_ns
+    assert sum(lt.other_ns for lt in s.layers.values()) == \
+        pytest.approx(s.other_ns)
+
+
+def test_pallas_roofline_leaves_out_the_stem(scoped_call):
+    from harness import work
+    from harness.spec import metric_reader
+
+    ops, spans, window, layer_work = scoped_call
+    s = tr.reduce(ops, spans, window,
+                  layer_names={lw.name for lw in layer_work})
+    ctx = _roofline_ctx(s, layer_work)
+    pallas_s = s.pallas_ns / 1e9
+    peaks = ctx.peaks()
+    every = 100 * work.roofline_seconds(layer_work, [1], *peaks) / pallas_s
+    stem = 100 * work.roofline_seconds(
+        [lw for lw in layer_work if lw.name == "conv1"], [1], *peaks) / pallas_s
+    got = metric_reader("pallas_roofline")(ctx)
+    assert got == pytest.approx(every - stem, rel=1e-12)
+    assert got < every
+
+
+def test_pallas_roofline_reads_nothing_for_a_kernel_without_a_scope(
+        scoped_call, chip_trace):
+    from harness.spec import metric_reader
+
+    ops, spans, window, layer_work = scoped_call
+    names = {lw.name for lw in layer_work}
+    read = metric_reader("pallas_roofline")
+    first = next(i for i, o in enumerate(ops) if tr.is_pallas(o[0]))
+    unscoped = list(ops)
+    unscoped[first] = ops[first][:3] + ("",)
+    s = tr.reduce(unscoped, spans, window, layer_names=names)
+    assert s.pallas_layers() is None
+    assert read(_roofline_ctx(s, layer_work)) is None
+    # the older recording, read without scopes
+    ops12, spans12, window12 = chip_trace
+    s = tr.reduce(ops12, spans12, window12, layer_names=names)
+    assert read(_roofline_ctx(s, layer_work)) is None
