@@ -21,7 +21,8 @@ class VSCNNMobileNetV1Config:
     num_classes: int = 1000
     # dw layers have only kh*kw tap vectors per channel tile, so the pruning
     # point is gentler than the paper's 0.235 VGG operating point: 0.5 keeps
-    # ceil(9 * 0.5) of 9 taps — enough to stay a conv, still a 2x tap skip.
+    # round(9 * 0.5) = 4 of 9 taps (Python rounds halves to even, as
+    # `prune_vectors_balanced` does) — enough to stay a conv, a 2.25x skip.
     weight_density: float = 0.5
     vk: int = 32                    # K-tile length (pointwise convs)
     vn: int = 128                   # output strip / dw channel-tile width

@@ -442,7 +442,8 @@ class CNNBackend:
     While a profiler session is on, each wave records `launch.spans`:
     ``backend.stack`` (the host batch), ``backend.put`` (its transfer),
     ``backend.launch`` (the jitted call; ``miss`` when it compiled,
-    ``xla_convs`` the conv layers it runs through XLA, not a kernel),
+    ``xla_convs`` the conv layers it runs through XLA, not a kernel,
+    ``dw_convs`` the depthwise conv layers it runs),
     ``backend.wait`` and ``backend.fetch`` (the result's copy to the host),
     and puts ``images`` (occupied slots) and ``rows`` (the batch computed)
     on the scheduler's ``backend.wave`` span.
@@ -517,7 +518,8 @@ class CNNBackend:
             compiles = self.apply.compiles
             y = self.apply(x)
             launch.set(miss=self.apply.compiles > compiles,
-                       xla_convs=self.apply.xla_convs)
+                       xla_convs=self.apply.xla_convs,
+                       dw_convs=self.apply.dw_convs)
         return occ, y
 
     def collect(self, state, handle, slots):

@@ -76,6 +76,7 @@ __all__ = [
     "ConvTileGeometry", "FCTileGeometry", "conv_tile_geometry",
     "fc_tile_geometry", "keeps_dense", "strip_steps",
     "sparse_conv_from_dense", "ORACLE_IMPLS", "runs_xla_conv",
+    "is_depthwise_conv",
     "apply_sparse_conv", "apply_sparse_fc",
     "weight_scales", "quantize_weights_int8", "quantize_activations_int8",
     "net_schema", "net_apply", "sparsify", "collect_conv_traffic",
@@ -550,6 +551,16 @@ def runs_xla_conv(entry: SparseConv | VectorSparse, impl: str) -> bool:
             and impl not in ORACLE_IMPLS)
 
 
+def is_depthwise_conv(entry: SparseConv | VectorSparse) -> bool:
+    """True for a depthwise conv kept sparse (``groups == cin == cout``,
+    its (kh*kw, C) tap matrix encoded with vk == 1), whatever the impl:
+    `vs_conv2d` runs it on the per-channel tap kernels, one
+    `vsconv_dw_halo_pallas` launch on a TPU."""
+    return (isinstance(entry, SparseConv) and entry.dense_w is None
+            and entry.groups > 1
+            and entry.vs.shape == (entry.kh * entry.kw, entry.groups))
+
+
 def apply_sparse_conv(x: jax.Array, entry: SparseConv | VectorSparse, *,
                       bias: jax.Array | None = None, fuse_relu: bool = True,
                       residual: jax.Array | None = None,
@@ -855,8 +866,10 @@ class BatchedApply:
     side.  By default each instance gets its own cache.
 
     ``xla_convs`` is the number of conv layers each executable runs
-    through XLA instead of a kernel (`runs_xla_conv`), fixed when
-    the instance is built: the same for every shape bucket.
+    through XLA instead of a kernel (`runs_xla_conv`), and ``dw_convs``
+    the number of depthwise conv layers it runs (`is_depthwise_conv`),
+    both fixed when the instance is built: the same for every shape
+    bucket.
 
     Sharded compile path: when ``mesh`` (+ ``rules``) is set, tracing and
     execution run inside ``sharding.use_mesh(mesh, rules)`` and the cache
@@ -876,10 +889,12 @@ class BatchedApply:
     mesh: object = None
     rules: object = None
     xla_convs: int = dataclasses.field(init=False)
+    dw_convs: int = dataclasses.field(init=False)
 
     def __post_init__(self) -> None:
-        self.xla_convs = sum(runs_xla_conv(e, self.impl)
-                             for e in (self.sparse or {}).values())
+        entries = (self.sparse or {}).values()
+        self.xla_convs = sum(runs_xla_conv(e, self.impl) for e in entries)
+        self.dw_convs = sum(map(is_depthwise_conv, entries))
 
     def cache_key(self, shape: tuple) -> tuple:
         # id() is stable and unique here: self (and every cached closure)
