@@ -36,7 +36,24 @@ Backend protocol (duck-typed)
   finish(state) -> dict
       Backend-specific stats merged into the run's stats dict.
 
-Optional protocol extensions (fault tolerance / admission control):
+Optional protocol extensions (run-ahead, fault tolerance, admission
+control):
+
+  dispatch(state, slots) -> handle
+  collect(state, handle, slots) -> (state, emissions)
+      ``step`` split in two: ``dispatch`` issues the wave and returns
+      without blocking on its result, ``collect`` blocks and emits.
+  one_shot = True
+      Every request finishes on the first emission of the wave that
+      computes it (``append`` returns True), ``backfill`` emits nothing,
+      and neither ``can_backfill`` nor ``backfill`` reads the emissions of
+      a wave still in flight.  A one-shot backend with the split runs one
+      wave ahead in `LockstepScheduler.run_lockstep`: the successor of
+      wave k (the requests `_deliver`'s first-fit backfill would pop for
+      the slots wave k frees, in the same order) is dispatched before wave
+      k is collected, so its host work overlaps wave k's device work.  At
+      most two waves are in flight.  Any other backend keeps the
+      synchronous loop: ``step``, deliver, backfill, ``step``.
 
   validate_request(req) -> str | None
       Admission-time request validation: a refusal reason string rejects
@@ -68,12 +85,8 @@ on the least-loaded replica.  The run loop interleaves the replicas'
 lockstep runs one step per tick — per-replica wave dispatch, so a slow
 wave on replica 0 never stalls retirement or backfill on replicas
 1..N-1 — and an idle replica *steals* the tail half of the longest queue
-still waiting on any other replica.  Backends may split ``step`` into
-
-  dispatch(state, slots) -> handle
-  collect(state, handle, slots) -> (state, emissions)
-
-so one tick issues every replica's computation before blocking on any
+still waiting on any other replica.  With the ``dispatch``/``collect``
+split one tick issues every replica's computation before blocking on any
 result (JAX async dispatch overlaps the replicas' device work); backends
 without the split fall back to the synchronous ``step``.  With one
 replica the ladder, admission order and step sequence are exactly
@@ -113,7 +126,9 @@ While a JAX profiler session is on, each ``serve`` call records
 (admission, bucketing and sorting; ``refused``), ``scheduler.run`` (one
 lockstep run; ``replica``), ``scheduler.deliver`` (one delivery pass) and
 ``backend.wave`` (one backend step, dispatch to collect; ``wave``,
-``replica``).  The clock readings of ``scheduler.run`` are the run stats'
+``replica``, and in a lockstep run ``ahead``: 1 when the wave was
+dispatched while the wave before it was still in flight, else 0).  The
+clock readings of ``scheduler.run`` are the run stats'
 ``start_s``/``run_s``; off a profiler session they are the only clock
 reads.
 """
@@ -177,15 +192,22 @@ def _deliver(be, state, slots, queue, emis, on_finish=None):
                 finished += 1
                 if on_finish is not None:
                     on_finish(req)
-                req = None
-                for qi, cand in enumerate(queue):
-                    if be.can_backfill(state, cand):
-                        req = queue.pop(qi)
-                        backfills += 1
-                        state, e = be.backfill(state, j, req)
-                        break
+                state, req, e = _backfill(be, state, queue, j)
+                backfills += req is not None
             slots[j] = req
     return state, finished, backfills, emitted
+
+
+def _backfill(be, state, queue, slot):
+    """First fit: pop the first request of ``queue`` that may join the run
+    and admit it into ``slot``.  Returns ``(state, req, emission)``, with
+    ``req`` None when nothing in the queue fits."""
+    for qi, cand in enumerate(queue):
+        if be.can_backfill(state, cand):
+            req = queue.pop(qi)
+            state, e = be.backfill(state, slot, req)
+            return state, req, e
+    return state, None, None
 
 
 def _admit(be, requests, outcomes, *, max_queue=None, wave=0):
@@ -265,24 +287,27 @@ class LockstepScheduler:
                     stats.append(self.run_lockstep(queue))
             return stats
 
-    def _on_finish(self, req) -> None:
-        _record(self.outcomes, req, RequestOutcome(
-            rid=getattr(req, "rid", None), status="delivered",
-            wave=self.waves))
+    def _on_finish(self, wave: int):
+        def cb(req):
+            _record(self.outcomes, req, RequestOutcome(
+                rid=getattr(req, "rid", None), status="delivered",
+                wave=wave))
+        return cb
 
     def run_lockstep(self, queue: list) -> dict:
         """One lockstep run: admit up to ``batch`` requests, step until every
         slot retires, backfilling freed slots from ``queue`` (consumed in
-        place).  Stats: steps, finished, backfills, emissions, start_s,
-        run_s (from the clock readings of the run's ``scheduler.run`` span),
-        plus whatever `backend.finish` adds.
+        place).  A one-shot backend with the dispatch/collect split runs one
+        wave ahead (`_run_ahead`).  Stats: steps, finished, backfills,
+        emissions, start_s, run_s (from the clock readings of the run's
+        ``scheduler.run`` span), plus whatever `backend.finish` adds.
         """
         be = self.backend
         assert queue, "run_lockstep needs at least one request"
         width = self.batch
         admitted = [queue.pop(0) for _ in range(min(width, len(queue)))]
         slots: list = admitted + [None] * (width - len(admitted))
-        steps = finished = backfills = emitted = 0
+        n = {"steps": 0, "finished": 0, "backfills": 0, "emissions": 0}
         ctx = getattr(be, "context", None)
         with (ctx() if ctx else contextlib.nullcontext()):
             t0 = time.time_ns()
@@ -290,31 +315,66 @@ class LockstepScheduler:
             with run:
                 state, emis = be.start(admitted, width)
                 t1 = time.time_ns()
-                while True:
-                    state, f, b, e = _deliver(be, state, slots, queue, emis,
-                                              self._on_finish)
-                    finished += f
-                    backfills += b
-                    emitted += e
-                    if all(s is None for s in slots):
-                        break
+                state = self._deliver(state, slots, queue, emis, n,
+                                      self.waves)
+                if getattr(be, "one_shot", False) and hasattr(be, "dispatch"):
+                    state = self._run_ahead(state, slots, queue, n)
+                while any(s is not None for s in slots):
                     self.waves += 1
                     with spans.span("backend.wave", wave=self.waves,
-                                    replica=0):
+                                    replica=0, ahead=0):
                         state, emis = be.step(state, slots)
-                    steps += 1
+                    n["steps"] += 1
+                    state = self._deliver(state, slots, queue, emis, n,
+                                          self.waves)
             t2 = time.time_ns()
             run.end(t2)
-        out = {
-            "steps": steps,
-            "finished": finished,
-            "backfills": backfills,
-            "emissions": emitted,
-            "start_s": (t1 - t0) / 1e9,
-            "run_s": (t2 - t1) / 1e9,
-        }
+        out = dict(n, start_s=(t1 - t0) / 1e9, run_s=(t2 - t1) / 1e9)
         out.update(be.finish(state) or {})
         return out
+
+    def _deliver(self, state, slots, queue, emis, n: dict, wave: int):
+        state, f, b, e = _deliver(self.backend, state, slots, queue, emis,
+                                  self._on_finish(wave))
+        n["finished"] += f
+        n["backfills"] += b
+        n["emissions"] += e
+        return state
+
+    def _run_ahead(self, state, slots, queue, n: dict):
+        """The waves of a one-shot backend with the dispatch/collect split,
+        each dispatched before the wave before it is collected.  A wave's
+        successor takes the requests `_deliver` would backfill into the
+        slots the wave frees, so every batch, count and outcome is the
+        synchronous loop's.  Leaves ``slots`` idle."""
+        be = self.backend
+        flight = None          # (wave, span, slots, handle) not collected
+        while any(s is not None for s in slots):
+            self.waves += 1
+            span = spans.begin("backend.wave", wave=self.waves, replica=0,
+                               ahead=int(flight is not None))
+            with span:
+                handle = be.dispatch(state, slots)
+            n["steps"] += 1
+            nxt = [None] * len(slots)
+            for j, req in enumerate(slots):
+                if req is not None:
+                    state, nxt[j], _ = _backfill(be, state, queue, j)
+            n["backfills"] += sum(r is not None for r in nxt)
+            if flight is not None:
+                state = self._collect(state, flight, n)
+            flight = (self.waves, span, slots, handle)
+            slots = nxt
+        if flight is not None:
+            state = self._collect(state, flight, n)
+        return state
+
+    def _collect(self, state, flight, n: dict):
+        wave, span, slots, handle = flight
+        with span:
+            state, emis = self.backend.collect(state, handle, slots)
+        span.end()
+        return self._deliver(state, slots, [], emis, n, wave)
 
 
 class _ReplicaRun:

@@ -432,9 +432,14 @@ class CNNBackend:
     sparse-path FLOPs.  The pow2 ladder bounds the compile count per shape
     bucket at log2(width)+1 executables.
 
-    ``step`` is split into ``dispatch`` (build the padded batch and issue
-    the jitted apply — JAX async dispatch returns before the device
-    finishes) and ``collect`` (block on the result): the fleet scheduler
+    ``step`` is split into ``dispatch`` (build the padded batch, issue its
+    transfer and the jitted apply — JAX async dispatch returns before the
+    device finishes) and ``collect`` (block on the result).  As the
+    backend is ``one_shot``, the lockstep scheduler dispatches wave k+1
+    before it collects wave k: wave k+1's batch build, transfer and launch
+    overlap wave k's device work, and the device starts wave k+1 with its
+    input resident.  Each wave builds a fresh host batch, so no buffer is
+    written while its transfer may be in flight.  The fleet scheduler
     dispatches every replica's wave before collecting any, so replicas'
     device work overlaps.  ``mesh``/``rules`` flow to `BatchedApply`'s
     sharded compile path (sharded FC heads — see `ReplicaGroup`).
@@ -448,6 +453,8 @@ class CNNBackend:
     and puts ``images`` (occupied slots) and ``rows`` (the batch computed)
     on the scheduler's ``backend.wave`` span.
     """
+
+    one_shot = True
 
     def __init__(self, net, params, *, sparse=None, impl: str = "auto",
                  density: float | None = None, image_size: int | None = None,

@@ -11,6 +11,8 @@ an event log, so cross-replica interleaving is asserted directly.
 """
 import dataclasses
 
+import pytest
+
 from repro.launch.scheduler import FleetScheduler, LockstepScheduler
 
 
@@ -152,6 +154,124 @@ class TestLockstep:
         assert stats[0]["backfills"] == 1
         assert be.started == [[0, 1], [2]]
         assert len(reqs[3].out) == 2     # rid 3 rode rid 0's slot
+
+
+class OneShotBackend:
+    """A one-shot backend with the dispatch/collect split, like the CNN
+    one: each request finishes on its wave's emission (``10 * rid``), and
+    every call is logged.  ``one_shot = False`` on an instance drives the
+    same backend through ``step`` only."""
+
+    one_shot = True
+
+    def __init__(self, admit=None):
+        self.admit = admit or (lambda req: True)
+        self.log = []                      # ("dispatch"|"collect", wave)
+        self.waves = []                    # rids of each dispatched wave
+        self.delivered = []                # rids in append order
+        self.in_flight = self.most_in_flight = 0
+
+    def bucket_key(self, req):
+        return 0
+
+    def sort_key(self, req):
+        return req.rid
+
+    def start(self, reqs, width):
+        return {}, None
+
+    def dispatch(self, state, slots):
+        k = len(self.waves)
+        self.waves.append([r.rid if r is not None else None for r in slots])
+        self.log.append(("dispatch", k))
+        self.in_flight += 1
+        self.most_in_flight = max(self.most_in_flight, self.in_flight)
+        return k
+
+    def collect(self, state, k, slots):
+        self.log.append(("collect", k))
+        self.in_flight -= 1
+        return state, [10 * rid if rid is not None else None
+                       for rid in self.waves[k]]
+
+    def step(self, state, slots):
+        return self.collect(state, self.dispatch(state, slots), slots)
+
+    def can_backfill(self, state, req):
+        return self.admit(req)
+
+    def backfill(self, state, slot, req):
+        return state, None
+
+    def append(self, req, e):
+        req.out.append(e)
+        self.delivered.append(req.rid)
+        return True
+
+    def finish(self, state):
+        return {}
+
+
+def _one_shot(n, batch, *, one_shot=True, admit=None):
+    be = OneShotBackend(admit)
+    be.one_shot = one_shot
+    sched = LockstepScheduler(be, batch=batch)
+    reqs = [Req(i, [], 1) for i in range(n)]
+    stats = sched.serve(reqs)
+    return be, sched, reqs, stats
+
+
+class TestRunAhead:
+    def test_next_wave_dispatched_before_current_collected(self):
+        """Four full waves: wave k+1 is dispatched before wave k is
+        collected, and never more than two waves are in flight."""
+        be, _, reqs, stats = _one_shot(8, 2)
+        assert be.waves == [[0, 1], [2, 3], [4, 5], [6, 7]]
+        assert be.log == [("dispatch", 0), ("dispatch", 1), ("collect", 0),
+                          ("dispatch", 2), ("collect", 1),
+                          ("dispatch", 3), ("collect", 2), ("collect", 3)]
+        assert be.most_in_flight == 2
+        assert [r.out for r in reqs] == [[10 * i] for i in range(8)]
+
+    # (requests, batch, can_backfill): 4 full waves, a partial tail wave,
+    # one request, a refused request that spills to a fresh run
+    @pytest.mark.parametrize("n,batch,admit", [
+        (8, 2, None), (7, 4, None), (1, 4, None), (13, 4, None),
+        (6, 2, lambda req: req.rid != 3),
+    ])
+    def test_same_waves_stats_and_outcomes_as_step(self, n, batch, admit):
+        """Run-ahead changes only when a wave is collected: its batches,
+        stats, outcomes and delivery order are those of the same backend
+        driven through ``step``."""
+        ahead, a_sched, a_reqs, a_stats = _one_shot(n, batch, admit=admit)
+        step, s_sched, s_reqs, s_stats = _one_shot(n, batch, admit=admit,
+                                                   one_shot=False)
+        assert step.most_in_flight == 1
+        assert ahead.waves == step.waves
+        assert ahead.delivered == step.delivered
+        assert [r.out for r in a_reqs] == [r.out for r in s_reqs]
+        keys = ("steps", "finished", "backfills", "emissions")
+        assert [{k: s[k] for k in keys} for s in a_stats] == \
+            [{k: s[k] for k in keys} for s in s_stats]
+        assert a_sched.outcomes == s_sched.outcomes
+        assert a_sched.waves == s_sched.waves == len(step.waves)
+
+    def test_one_request_is_one_wave(self):
+        be, _, reqs, stats = _one_shot(1, 4)
+        assert be.log == [("dispatch", 0), ("collect", 0)]
+        assert be.waves == [[0, None, None, None]]
+        assert stats[0]["steps"] == 1 and stats[0]["backfills"] == 0
+        assert reqs[0].outcome.wave == 1
+
+    def test_partial_tail_wave(self):
+        """Seven requests at width 4: the tail wave holds the three
+        backfills and an idle lane, dispatched while the full wave runs."""
+        be, _, reqs, stats = _one_shot(7, 4)
+        assert be.waves == [[0, 1, 2, 3], [4, 5, 6, None]]
+        assert be.log == [("dispatch", 0), ("dispatch", 1), ("collect", 0),
+                          ("collect", 1)]
+        assert stats[0]["backfills"] == 3 and stats[0]["steps"] == 2
+        assert [r.outcome.wave for r in reqs] == [1] * 4 + [2] * 3
 
 
 class FleetScript(ScriptBackend):

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.scheduler import FleetScheduler, LockstepScheduler
 from repro.launch.serve import (
     CNNServer, ImageRequest, Request, Server, random_prompt_lengths,
 )
@@ -295,6 +296,31 @@ class TestCNNServing:
         assert reasons[5].startswith("invalid:prompt_too_long")
         for r in bad:
             assert r.out == []
+
+    def test_run_ahead_matches_dispatch_then_collect(self):
+        """The lockstep scheduler runs one wave ahead of its collects; the
+        one-replica fleet (a deadline puts it on that path) collects each
+        wave before it dispatches the next.  Over four waves, the last a
+        partial one, both deliver bit-identical logits and outcomes."""
+        cfg = get_config("vscnn-vgg16").reduce()
+        rng = np.random.default_rng(6)
+        imgs = [rng.standard_normal((32, 32, 3)).astype(np.float32)
+                for _ in range(13)]
+        served = []
+        for kw, kind in (({}, LockstepScheduler),
+                         ({"deadline_waves": 1000}, FleetScheduler)):
+            srv = CNNServer(cfg, batch=4, seed=0, **kw)
+            assert type(srv.scheduler) is kind
+            reqs = [ImageRequest(rid=i, image=im)
+                    for i, im in enumerate(imgs)]
+            stats = srv.serve(reqs)
+            assert sum(s["steps"] for s in stats) == 4
+            served.append(reqs)
+        for a, b in zip(*served):
+            np.testing.assert_array_equal(a.logits, b.logits)
+            assert a.out == b.out
+            assert (a.outcome.status, a.outcome.reason, a.outcome.wave) == \
+                (b.outcome.status, b.outcome.reason, b.outcome.wave)
 
     def test_lockstep_max_queue_sheds(self):
         """Bounded admission: requests beyond the depth are shed with a

@@ -119,6 +119,29 @@ def test_partial_wave_counts_images_and_rows(server, tmp_path):
     assert wave.attrs["replica"] == 0
 
 
+@pytest.mark.parametrize("n,ahead", [(20, [0, 1, 1]), (1, [0])])
+def test_ahead_marks_waves_dispatched_in_flight(server, tmp_path, n, ahead):
+    """Width 8: each wave after a run's first is dispatched while the one
+    before it is in flight (``ahead`` 1), and each wave's own stack, put,
+    launch, wait and fetch lie inside its span, though the spans of
+    successive waves overlap."""
+    _, rec = _traced(tmp_path, server.serve, _requests(server, n, 500))
+    waves = sorted((s for s in rec if s.name == "backend.wave"),
+                   key=lambda s: s.attrs["wave"])
+    assert [w.attrs["ahead"] for w in waves] == ahead
+    by_id = {s.id: s for s in rec}
+    for w in waves:
+        kids = [s.name for s in rec if s.parent == w.id]
+        assert kids == ["backend.stack", "backend.put", "backend.launch",
+                        "backend.wait", "backend.fetch"]
+    for w, nxt in zip(waves, waves[1:]):
+        assert nxt.start_ns < w.end_ns
+    for s in rec:
+        if s.name.startswith("backend.") and s.name != "backend.wave":
+            w = by_id[s.parent]
+            assert w.start_ns <= s.start_ns <= s.end_ns <= w.end_ns
+
+
 def test_miss_only_on_the_first_call_of_a_shape(server, tmp_path):
     """Two requests run on a width-2 batch, which nothing else here
     serves: the first call compiles it, the second does not."""
