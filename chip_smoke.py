@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Chip smoke run: sparse ResNet-50 served at its published size on a TPU.
 
-Drives the serving path a user calls — `CNNServer` -> `LockstepScheduler`
+Drives the serving path a user calls — `CNNServer` -> `FleetScheduler`
 -> `BatchedApply` / `net_apply` -> the Pallas vector-sparse kernels — with
 ``vscnn-resnet50`` at its published size (224x224x3 images, 1000 classes,
 weight density 0.235, f32), random weights and images from ``--seed``, and

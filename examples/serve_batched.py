@@ -1,4 +1,4 @@
-"""Batched serving on CPU (reduced configs) through the lockstep scheduler.
+"""Batched serving on CPU (reduced configs) through the scheduler loop.
 
 LM mode (default): the production prefill/decode jits with continuous
 batching — EOS/budget retirement and in-run slot backfill — the same step
@@ -49,7 +49,7 @@ def serve_cnn(args) -> None:
     run_s = sum(st["run_s"] for st in stats)
     backfills = sum(st["backfills"] for st in stats)
     used = sorted({st.get("replica", 0) for st in stats})
-    print(f"served {total} images in {len(stats)} lockstep runs "
+    print(f"served {total} images in {len(stats)} runs "
           f"({backfills} backfills), {total / max(run_s, 1e-9):.1f} img/s "
           f"(density {srv.density}, replicas used {used}, "
           f"shard_fc={args.shard_fc}; CPU, reduced config)")
@@ -73,7 +73,7 @@ def serve_lm(args) -> None:
     total = sum(s["new_tokens"] for s in stats)
     dec_s = sum(s["decode_s"] for s in stats)
     backfills = sum(s["backfills"] for s in stats)
-    print(f"served {args.requests} requests in {len(stats)} lockstep runs "
+    print(f"served {args.requests} requests in {len(stats)} runs "
           f"({backfills} backfills)")
     print(f"{total} tokens generated, decode throughput "
           f"{total / max(dec_s, 1e-9):.1f} tok/s (CPU, reduced config)")

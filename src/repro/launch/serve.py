@@ -1,7 +1,8 @@
-"""Model-agnostic batched serving: one lockstep scheduler, two backends.
+"""Model-agnostic batched serving: one scheduler loop, two backends.
 
-`launch.scheduler.LockstepScheduler` owns the queue, batch bucketing, slot
-retirement and backfill; this module plugs in the model math:
+`launch.scheduler.FleetScheduler` owns the queue, batch bucketing, slot
+retirement, backfill and replica placement (one backend is a fleet of
+one); this module plugs in the model math:
 
 * `LMBackend` / `Server` — the production prefill/decode jits with working
   continuous batching.  A sequence retires the moment it emits ``eos_id``
@@ -21,13 +22,15 @@ retirement and backfill; this module plugs in the model math:
 
 * `CNNBackend` / `CNNServer` — CNN inference traffic through
   `SparseNet.apply`: requests carry images, batches pad/bucket on image
-  shape, every request finishes in one lockstep step, and freed slots are
-  refilled from the queue so the compiled batch shape is reused wave after
-  wave; a partial final wave shrinks to its occupied slots (pow2 ladder)
-  instead of computing zero images.  A jit cache keyed on (net, density,
-  impl, batch bucket) — see `models.graph.BatchedApply` — keeps recompiles
-  off the hot path; ``impl`` defaults to ``auto`` (the halo-layout Pallas
-  conv kernels on TPU, the structural jnp path elsewhere).
+  shape, every request finishes in one wave, and freed slots are refilled
+  from the queue so the compiled batch shape is reused wave after wave; a
+  partial final wave shrinks to its occupied slots (pow2 ladder) instead
+  of computing zero images.  The backend is one-shot with the
+  dispatch/collect split, so the scheduler runs it one wave ahead.  A jit
+  cache keyed on (net, density, impl, batch bucket) — see
+  `models.graph.BatchedApply` — keeps recompiles off the hot path;
+  ``impl`` defaults to ``auto`` (the halo-layout Pallas conv kernels on
+  TPU, the structural jnp path elsewhere).
 
 Both run end-to-end on CPU with reduced configs; the LM jits are the same
 step functions the decode_32k / long_500k dry-run cells lower on the
@@ -35,11 +38,10 @@ production mesh.
 
 Multi-device serving: ``--replicas N`` serves a `ReplicaGroup` — N
 data-parallel CNN backend instances with `jax.device_put`-placed weight
-copies — behind `launch.scheduler.FleetScheduler` (per-replica wave
-dispatch, least-loaded placement, work stealing).  ``--shard-fc``
-additionally cout-shards the FC heads' strips over each replica's
-``model`` devices (`models.graph.shard_sparse`).  On CPU, force a device
-mesh with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+copies — with per-replica waves, least-loaded placement and work stealing.
+``--shard-fc`` additionally cout-shards the FC heads' strips over each
+replica's ``model`` devices (`models.graph.shard_sparse`).  On CPU, force
+a device mesh with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 LM requests carry per-request sampling params (``temperature`` /
 ``top_k``); temperature 0 is greedy argmax, bit-identical to the
@@ -49,12 +51,12 @@ Fault tolerance: both backends validate requests at admission
 (`validate_request` — malformed images / prompts become structured
 `RequestOutcome` refusals, never mid-wave shape errors), `CNNBackend`
 guards its outputs (`check_emission` — non-finite logits quarantine the
-producing replica), and `CNNServer` accepts a ``fault_plan``
-(`launch.faults.FaultPlan`) that wraps every replica in a `ChaosBackend`
-for deterministic chaos runs, plus ``max_queue`` / ``deadline_waves`` /
-``max_attempts`` budgets forwarded to the schedulers.  Per-request
-outcomes of the last serve land on ``srv.outcomes`` (and each request's
-``.outcome``).
+producing replica, one replica included), and `CNNServer` accepts a
+``fault_plan`` (`launch.faults.FaultPlan`) that wraps every replica in a
+`ChaosBackend` for deterministic chaos runs, plus ``max_queue`` /
+``deadline_waves`` / ``max_attempts`` budgets forwarded to the scheduler.
+Per-request outcomes of the last serve land on ``srv.outcomes`` (and each
+request's ``.outcome``).
 
 Usage (CPU examples):
   python -m repro.launch.serve --arch rwkv6-3b --requests 16 --tokens 32
@@ -76,7 +78,7 @@ from repro.configs import get_config
 from repro.launch import spans
 from repro.launch.faults import ChaosBackend, FaultPlan
 from repro.launch.mesh import make_local_mesh
-from repro.launch.scheduler import FleetScheduler, LockstepScheduler
+from repro.launch.scheduler import FleetScheduler
 from repro.models import transformer as tfm
 from repro.models.layers import init_params
 from repro.parallel import sharding as shd
@@ -123,7 +125,7 @@ class ImageRequest:
 
 
 # --------------------------------------------------------------------------
-# LM backend: prefill/decode lockstep with EOS retirement + cache-merge
+# LM backend: prefill/decode waves with EOS retirement + cache-merge
 # backfill
 # --------------------------------------------------------------------------
 
@@ -356,7 +358,8 @@ class LMBackend:
 
 
 class Server:
-    """Batched LM serving: prefill/decode behind the lockstep scheduler."""
+    """Batched LM serving: prefill/decode behind the fleet scheduler (a
+    fleet of one)."""
 
     def __init__(self, cfg, *, batch: int, capacity: int, seed: int = 0,
                  mesh=None, eos_id: int | None = None, len_bucket: int = 16,
@@ -372,8 +375,8 @@ class Server:
         self.backend = LMBackend(cfg, self.params, self.mesh,
                                  capacity=capacity, eos_id=eos_id,
                                  len_bucket=len_bucket)
-        self.scheduler = LockstepScheduler(self.backend, batch=batch,
-                                           max_queue=max_queue)
+        self.scheduler = FleetScheduler([self.backend], batch=batch,
+                                        max_queue=max_queue)
 
     @property
     def outcomes(self) -> dict:
@@ -393,20 +396,20 @@ class Server:
         }
 
     def run_batch(self, requests: list[Request]) -> dict:
-        """One lockstep run: the first ``batch`` requests are admitted, the
-        rest backfill retired slots.  Returns timing stats.  Raises if a
+        """One run: the first ``batch`` requests are admitted, the rest
+        backfill retired slots.  Returns timing stats.  Raises if a
         request can never join this run (capacity/context limits) — use
         `serve`, which gives leftovers a fresh run, for the general case."""
         queue = list(requests)
-        stats = self.scheduler.run_lockstep(queue)
+        stats = self.scheduler.run_once(queue)
         if queue:
             raise ValueError(
                 f"{len(queue)} request(s) could not backfill into this "
-                f"lockstep run (capacity/context limits); use serve()")
+                f"run (capacity/context limits); use serve()")
         return self._legacy_stats(stats)
 
     def serve(self, requests: list[Request]) -> list[dict]:
-        """Bucket the queue by prompt length, then run lockstep batches with
+        """Bucket the queue by prompt length, then run batches with
         retirement + backfill until it drains (continuous batching)."""
         return [self._legacy_stats(s)
                 for s in self.scheduler.serve(list(requests))]
@@ -417,7 +420,7 @@ class Server:
 # --------------------------------------------------------------------------
 
 class CNNBackend:
-    """One-shot image backend: a request finishes in a single lockstep step.
+    """One-shot image backend: a request finishes in a single wave.
 
     Slot reuse across waves is the batch-reuse story — the compiled
     (width, H, W, C) executable from `models.graph.BatchedApply` serves
@@ -435,11 +438,11 @@ class CNNBackend:
     ``step`` is split into ``dispatch`` (build the padded batch, issue its
     transfer and the jitted apply — JAX async dispatch returns before the
     device finishes) and ``collect`` (block on the result).  As the
-    backend is ``one_shot``, the lockstep scheduler dispatches wave k+1
-    before it collects wave k: wave k+1's batch build, transfer and launch
-    overlap wave k's device work, and the device starts wave k+1 with its
-    input resident.  Each wave builds a fresh host batch, so no buffer is
-    written while its transfer may be in flight.  The fleet scheduler
+    backend is ``one_shot``, the scheduler dispatches each replica's wave
+    k+1 before it collects wave k: wave k+1's batch build, transfer and
+    launch overlap wave k's device work, and the device starts wave k+1
+    with its input resident.  Each wave builds a fresh host batch, so no
+    buffer is written while its transfer may be in flight.  One tick
     dispatches every replica's wave before collecting any, so replicas'
     device work overlaps.  ``mesh``/``rules`` flow to `BatchedApply`'s
     sharded compile path (sharded FC heads — see `ReplicaGroup`).
@@ -547,7 +550,7 @@ class CNNBackend:
         return self.bucket_key(req) == state["bucket"]
 
     def backfill(self, state, slot: int, req: ImageRequest):
-        return state, None  # computed on the next lockstep step
+        return state, None  # computed on the next wave
 
     def append(self, req: ImageRequest, logits) -> bool:
         req.logits = np.asarray(logits)
@@ -569,8 +572,8 @@ class ReplicaGroup:
     slice of the cout-sharded `vsmm`, GSPMD all-gathers the logits in the
     epilogue).  Each replica holds its own `jax.device_put` copy of the
     params and sparse trees, so each compiles an executable resident on
-    its own devices and the fleet scheduler's dispatch-all-then-collect
-    tick overlaps the replicas' device work.
+    its own devices and the scheduler's dispatch-all-then-collect tick
+    overlaps the replicas' device work.
     """
 
     def __init__(self, net, params, *, sparse=None, impl: str = "auto",
@@ -620,18 +623,19 @@ def validate_net(net, image_size: int, *, density: float | None = None,
 
 
 class CNNServer:
-    """Batched CNN serving: `SparseNet.apply` behind the lockstep scheduler.
+    """Batched CNN serving: `SparseNet.apply` behind the fleet scheduler.
 
     ``cfg`` is a VSCNN config (`configs.vscnn_vgg16` / `vscnn_resnet18`):
     ``cfg.build()`` gives the `SparseNet`, ``cfg.weight_density`` the
     default pruning point.  ``sparse=False`` serves the dense jnp path (the
     XLA conv baseline the benchmarks compare against).
 
-    ``replicas > 1`` (or ``shard_fc``) serves a `ReplicaGroup` behind the
-    `FleetScheduler` — per-replica wave dispatch over device-placed weight
-    copies, with the FC heads optionally cout-sharded over each replica's
-    ``model`` devices.  One replica without sharding keeps the exact
-    single-backend `LockstepScheduler` path.
+    One replica without ``shard_fc`` serves one `CNNBackend` over the
+    server's own ``params``, with no device placement and no mesh.
+    ``replicas > 1`` (or ``shard_fc``) serves a `ReplicaGroup` —
+    device-placed weight copies, with the FC heads optionally
+    cout-sharded over each replica's ``model`` devices.  ``fault_plan``
+    wraps every replica in a `ChaosBackend`.
     """
 
     def __init__(self, cfg, *, batch: int, impl: str = "auto",
@@ -662,31 +666,24 @@ class CNNServer:
             # activations quantized per-tensor at apply time
             self.sparse, self.pruned = self.net.sparsify(
                 self.params, self.density, vk=cfg.vk, vn=cfg.vn, dtype=dtype)
-        image_size = cfg.image_size if cfg.fixed_image_size else None
-        fleet = (replicas > 1 or shard_fc or fault_plan is not None
-                 or deadline_waves is not None)
-        if not fleet:
-            self.backend = CNNBackend(
-                self.net, self.params, sparse=self.sparse, impl=impl,
-                density=self.density if sparse else None,
-                image_size=image_size, pad_multiple=pad_multiple)
-            self.backends = [self.backend]
-            self.scheduler = LockstepScheduler(self.backend, batch=batch,
-                                               max_queue=max_queue)
-        else:
-            self.group = ReplicaGroup(
-                self.net, self.params, sparse=self.sparse, impl=impl,
-                density=self.density if sparse else None,
-                image_size=image_size, pad_multiple=pad_multiple,
-                replicas=replicas, shard_fc=shard_fc, validate=False)
+        kw = dict(sparse=self.sparse, impl=impl,
+                  density=self.density if sparse else None,
+                  image_size=cfg.image_size if cfg.fixed_image_size else None,
+                  pad_multiple=pad_multiple)
+        if replicas > 1 or shard_fc:
+            self.group = ReplicaGroup(self.net, self.params,
+                                      replicas=replicas, shard_fc=shard_fc,
+                                      validate=False, **kw)
             self.backends = self.group.backends
-            if fault_plan is not None:
-                self.backends = [ChaosBackend(b, fault_plan, replica=i)
-                                 for i, b in enumerate(self.backends)]
-            self.backend = self.backends[0]
-            self.scheduler = FleetScheduler(
-                self.backends, batch=batch, max_queue=max_queue,
-                deadline_waves=deadline_waves, max_attempts=max_attempts)
+        else:
+            self.backends = [CNNBackend(self.net, self.params, **kw)]
+        if fault_plan is not None:
+            self.backends = [ChaosBackend(b, fault_plan, replica=i)
+                             for i, b in enumerate(self.backends)]
+        self.backend = self.backends[0]
+        self.scheduler = FleetScheduler(
+            self.backends, batch=batch, max_queue=max_queue,
+            deadline_waves=deadline_waves, max_attempts=max_attempts)
 
     @property
     def outcomes(self) -> dict:
@@ -739,12 +736,12 @@ def main():
     ap.add_argument("--top-k", type=int, default=0,
                     help="LM top-k truncation (0 = full vocab)")
     ap.add_argument("--chaos-seed", type=int, default=None,
-                    help="CNN fleet: inject a seeded FaultPlan "
-                         "(deterministic chaos; forces the fleet path)")
+                    help="CNN: inject a seeded FaultPlan "
+                         "(deterministic chaos)")
     ap.add_argument("--max-queue", type=int, default=None,
                     help="bounded admission depth (load shedding)")
     ap.add_argument("--deadline-waves", type=int, default=None,
-                    help="CNN fleet: per-request deadline in fleet ticks")
+                    help="CNN: per-request deadline in scheduler ticks")
     args = ap.parse_args()
     enable_compile_cache()
     if (args.arch is None) == (args.cnn is None):
@@ -770,7 +767,7 @@ def main():
         stats = srv.serve(reqs)
         wall = time.time() - t0
         tot = sum(st["images"] for st in stats)
-        print(f"served {tot} images in {len(stats)} lockstep runs, "
+        print(f"served {tot} images in {len(stats)} runs, "
               f"{tot / max(wall, 1e-9):.1f} img/s "
               f"(density {srv.density}, batch {args.batch}, "
               f"replicas {args.replicas}"
@@ -809,7 +806,7 @@ def main():
     stats = srv.serve(reqs)
     tot_new = sum(s["new_tokens"] for s in stats)
     tot_dec = sum(s["decode_s"] for s in stats)
-    print(f"served {len(reqs)} requests in {len(stats)} lockstep runs: "
+    print(f"served {len(reqs)} requests in {len(stats)} runs: "
           f"{tot_new} tokens, {tot_new/max(tot_dec,1e-9):.1f} tok/s decode")
     for s in stats:
         print("  ", {k: (round(v, 4) if isinstance(v, float) else v)

@@ -14,14 +14,15 @@ import dataclasses
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
-from test_scheduler import FleetScript, Req, ScriptBackend
+from test_scheduler import (
+    FleetOneShot, FleetScript, OneShotBackend, Req, ScriptBackend, StepOnly,
+)
 
 from repro.launch.faults import (
     ChaosBackend, CompileFault, Fault, FaultPlan, ReplicaDead,
 )
 from repro.launch.scheduler import (
     DRAINED, HEALTHY, QUARANTINED, SUSPECT, FleetScheduler,
-    LockstepScheduler,
 )
 
 
@@ -93,25 +94,26 @@ class TestPlan:
 class TestChaosTransparency:
     def test_empty_plan_fleet_of_one_matches_lockstep(self):
         """The invariant everything else builds on: one chaos-wrapped
-        replica with an empty plan is bit-identical to the plain
-        `LockstepScheduler` — admission waves, stats, outputs, outcomes."""
-        mk = lambda: [Req(0, [1] * 8, 2), Req(1, [2] * 8, 6),
-                      Req(2, [3] * 8, 3)]
-        solo_be = ScriptBackend()
-        solo_reqs = mk()
-        solo_sched = LockstepScheduler(solo_be, batch=2)
-        solo = solo_sched.serve(solo_reqs)
-        sched, bes, _ = _chaos_fleet(1, 2, FaultPlan())
-        fleet_reqs = mk()
-        fleet = sched.serve(fleet_reqs)
-        assert [r.out for r in fleet_reqs] == [r.out for r in solo_reqs]
-        assert bes[0].inner.started == solo_be.started
+        replica with an empty plan, running one wave ahead, is
+        bit-identical to the same backend on the synchronous tick —
+        batches, stats, outputs, outcomes."""
+        def run(wrap):
+            be = OneShotBackend()
+            sched = FleetScheduler([wrap(be)], batch=2)
+            reqs = [Req(i, [], 1) for i in range(7)]
+            stats = sched.serve(reqs)
+            return be, sched, reqs, stats
+        chaos, c_sched, c_reqs, c_stats = run(
+            lambda be: ChaosBackend(be, FaultPlan(), replica=0))
+        step, s_sched, s_reqs, s_stats = run(StepOnly)
+        assert chaos.most_in_flight == 2 and step.most_in_flight == 1
+        assert chaos.waves == step.waves
+        assert [r.out for r in c_reqs] == [r.out for r in s_reqs]
         keys = ("steps", "finished", "backfills", "emissions")
-        assert [{k: s[k] for k in keys} for s in fleet] == \
-            [{k: s[k] for k in keys} for s in solo]
-        assert sched.health == [HEALTHY] and sched.fault_events == []
-        assert {rid: o.status for rid, o in sched.outcomes.items()} == \
-            {rid: o.status for rid, o in solo_sched.outcomes.items()}
+        assert [{k: s[k] for k in keys} for s in c_stats] == \
+            [{k: s[k] for k in keys} for s in s_stats]
+        assert c_sched.health == [HEALTHY] and c_sched.fault_events == []
+        assert c_sched.outcomes == s_sched.outcomes
 
     def test_empty_plan_never_fires(self):
         sched, bes, _ = _chaos_fleet(2, 2, FaultPlan())
@@ -150,6 +152,28 @@ class TestReplicaDeath:
         _check_terminal(sched, reqs)
         assert all(o.status == "delivered"
                    for o in sched.outcomes.values())
+
+    def test_die_collect_with_next_wave_in_flight(self):
+        """Replica 0 dies collecting its first wave while its second is
+        in flight: both waves and its queue move to replica 1, and every
+        request is delivered exactly once."""
+        plan = FaultPlan([Fault("die_collect", 0, 1)])
+        events = []
+        inner = [FleetOneShot(i, events) for i in range(2)]
+        bes = [ChaosBackend(b, plan, replica=i)
+               for i, b in enumerate(inner)]
+        sched = FleetScheduler(bes, batch=2)
+        reqs = [Req(i, [], 1) for i in range(12)]
+        sched.serve(reqs)
+        assert sched.health == [DRAINED, HEALTHY]
+        assert [e["fault"] for e in sched.fault_events] == ["ReplicaDead"]
+        # the fault hit wave 0 with wave 1 dispatched behind it
+        assert inner[0].waves == [[0, 1], [4, 5]]
+        assert inner[0].delivered == []
+        assert sorted(inner[1].delivered) == list(range(12))
+        assert all(o.status == "delivered" and o.replica == 1
+                   for o in sched.outcomes.values())
+        assert [r.out for r in reqs] == [[10 * i] for i in range(12)]
 
     def test_partial_stream_lost_without_reset(self):
         """A request whose delivery already started can only be re-served
@@ -240,6 +264,24 @@ class TestHealthStates:
         assert sched.health == [HEALTHY, HEALTHY]  # a stall is not a fault
         assert sched.steals >= 1
         assert ("stall" in [k for _, k in bes[0].injected])
+
+    def test_stalled_wave_running_ahead_is_served_later(self):
+        """A one-shot replica running ahead: a stalled wave emits nothing,
+        its requests go back to the head of the queue and ride a later
+        wave — each delivered exactly once, and a stall is not a fault."""
+        plan = FaultPlan([Fault("stall", 0, 1, ticks=2)])
+        inner = OneShotBackend()
+        sched = FleetScheduler([ChaosBackend(inner, plan, replica=0)],
+                               batch=2)
+        reqs = [Req(i, [], 1) for i in range(7)]
+        sched.serve(reqs)
+        assert [k for _, k in sched.backends[0].injected] == ["stall"] * 2
+        assert sched.health == [HEALTHY] and sched.fault_events == []
+        # the stalled waves [0, 1] and [2, 3] never reached the backend
+        assert inner.waves == [[4, 5], [0, 1], [2, 3], [6, None]]
+        assert sorted(inner.delivered) == list(range(7))
+        assert [r.out for r in reqs] == [[10 * i] for i in range(7)]
+        assert all(o.status == "delivered" for o in sched.outcomes.values())
 
 
 class TestBudgets:
@@ -430,6 +472,23 @@ class TestCNNFaultSweep:
         assert sched.health[0] == DRAINED
         assert [e["fault"] for e in sched.fault_events] == \
             ["NonFiniteOutput"]
+
+    def test_one_replica_refuses_non_finite_output(self, cnn):
+        """A one-replica server runs the same guard: non-finite logits
+        quarantine its only replica, and the wave is refused instead of
+        delivered."""
+        cfg, _ = cnn
+        plan = FaultPlan([Fault("nan", 0, 1)])
+        srv = CNNServer(cfg, batch=2, seed=0, fault_plan=plan,
+                        validate=False)
+        reqs = _images(cfg, 4)
+        srv.serve(reqs)
+        assert srv.scheduler.health == [DRAINED]
+        assert [e["fault"] for e in srv.scheduler.fault_events] == \
+            ["NonFiniteOutput"]
+        assert all(o.status == "refused" and o.reason == "no_healthy_replicas"
+                   for o in srv.outcomes.values())
+        assert all(r.logits is None for r in reqs)
 
     def test_cnn_chaos_replay_identical(self, cnn):
         """Same seeded plan, fresh fleets: identical health, fault

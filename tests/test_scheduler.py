@@ -1,8 +1,8 @@
-"""Lockstep scheduler unit tests (no jax): bucketing, retirement order,
-backfill (including instant-finish chaining), can_backfill refusal — plus
-the replica fleet: per-replica wave dispatch (a stalled replica never
-blocks the others' retirement), least-loaded placement, work stealing,
-and N-replica output parity with the single-replica scheduler.
+"""Scheduler unit tests (no model): bucketing, retirement order, backfill
+(including instant-finish chaining), can_backfill refusal, running one
+wave ahead — plus the replica fleet: per-replica wave dispatch (a stalled
+replica never blocks the others' retirement), least-loaded placement,
+whole-wave work stealing, and N-replica output parity with one replica.
 
 A scripted pure-python backend stands in for the model: each request
 carries the emission stream its slot will produce, so slot lifecycle logic
@@ -13,7 +13,8 @@ import dataclasses
 
 import pytest
 
-from repro.launch.scheduler import FleetScheduler, LockstepScheduler
+from repro.launch import spans
+from repro.launch.scheduler import FleetScheduler
 
 
 @dataclasses.dataclass
@@ -69,8 +70,22 @@ class ScriptBackend:
         return {"custom": 1}
 
 
+class StepOnly:
+    """Hides a backend's dispatch/collect split: the scheduler then runs
+    the synchronous tick, collecting each wave before dispatching the
+    next."""
+
+    def __init__(self, inner):
+        self.inner = inner
+
+    def __getattr__(self, name):
+        if name in ("dispatch", "collect"):
+            raise AttributeError(name)
+        return getattr(self.inner, name)
+
+
 def _sched(be, batch):
-    return LockstepScheduler(be, batch=batch)
+    return FleetScheduler([be], batch=batch)
 
 
 class TestLockstep:
@@ -89,7 +104,7 @@ class TestLockstep:
 
     def test_retired_slot_backfilled_same_run(self):
         """A short sequence frees its slot for a queued request within the
-        same lockstep run."""
+        same run."""
         be = ScriptBackend()
         reqs = [Req(0, [1] * 8, 2), Req(1, [2] * 8, 6), Req(2, [3] * 8, 3)]
         stats = _sched(be, 2).serve(reqs)
@@ -132,7 +147,7 @@ class TestLockstep:
         assert be.started == [[1, 0], [2, 3]]
 
     def test_can_backfill_refusal_spills_to_new_run(self):
-        """A request the backend refuses mid-run gets a fresh lockstep run
+        """A request the backend refuses mid-run gets a fresh run
         instead of being dropped."""
         be = ScriptBackend(admit=lambda req: req.rid != 2)
         reqs = [Req(0, [1] * 4, 2), Req(1, [2] * 4, 2), Req(2, [3] * 4, 2)]
@@ -160,7 +175,7 @@ class OneShotBackend:
     """A one-shot backend with the dispatch/collect split, like the CNN
     one: each request finishes on its wave's emission (``10 * rid``), and
     every call is logged.  ``one_shot = False`` on an instance drives the
-    same backend through ``step`` only."""
+    same backend through the synchronous tick."""
 
     one_shot = True
 
@@ -215,7 +230,7 @@ class OneShotBackend:
 def _one_shot(n, batch, *, one_shot=True, admit=None):
     be = OneShotBackend(admit)
     be.one_shot = one_shot
-    sched = LockstepScheduler(be, batch=batch)
+    sched = FleetScheduler([be], batch=batch)
     reqs = [Req(i, [], 1) for i in range(n)]
     stats = sched.serve(reqs)
     return be, sched, reqs, stats
@@ -272,6 +287,78 @@ class TestRunAhead:
                           ("collect", 1)]
         assert stats[0]["backfills"] == 3 and stats[0]["steps"] == 2
         assert [r.outcome.wave for r in reqs] == [1] * 4 + [2] * 3
+
+
+class FleetOneShot(OneShotBackend):
+    """One fleet replica's one-shot backend; all replicas share
+    ``events``, so the order of dispatches and collects across replicas
+    is observable."""
+
+    def __init__(self, replica, events, **kw):
+        super().__init__(**kw)
+        self.replica = replica
+        self.events = events
+
+    def dispatch(self, state, slots):
+        k = super().dispatch(state, slots)
+        self.events.append(("dispatch", self.replica, k))
+        return k
+
+    def collect(self, state, k, slots):
+        self.events.append(("collect", self.replica, k))
+        return super().collect(state, k, slots)
+
+
+class TestFleetRunAhead:
+    def test_each_replica_runs_one_wave_ahead(self, monkeypatch):
+        """Two replicas, three waves each: every tick dispatches on both
+        replicas before it collects on either, each replica dispatches
+        wave k+1 before it collects wave k (``ahead`` 0, 1, 1), and no
+        replica ever has more than two waves in flight."""
+        monkeypatch.setattr(spans, "profiler_active", lambda: True)
+        events = []
+        bes = [FleetOneShot(i, events) for i in range(2)]
+        sched = FleetScheduler(bes, batch=2)
+        reqs = [Req(i, [], 1) for i in range(12)]
+        spans.clear()
+        sched.serve(reqs)
+        rec = spans.recorded()
+        spans.clear()
+        d = lambda i, k: ("dispatch", i, k)
+        c = lambda i, k: ("collect", i, k)
+        assert events == [d(0, 0), d(1, 0),
+                          d(0, 1), d(1, 1), c(0, 0), c(1, 0),
+                          d(0, 2), d(1, 2), c(0, 1), c(0, 2),
+                          c(1, 1), c(1, 2)]
+        assert [be.most_in_flight for be in bes] == [2, 2]
+        assert bes[0].waves == [[0, 1], [4, 5], [8, 9]]
+        assert bes[1].waves == [[2, 3], [6, 7], [10, 11]]
+        waves = [s for s in rec if s.name == "backend.wave"]
+        for i in range(2):
+            mine = sorted((s.attrs["wave"], s.attrs["ahead"]) for s in waves
+                          if s.attrs["replica"] == i)
+            assert mine == [(1, 0), (2, 1), (3, 1)]
+        assert [r.out for r in reqs] == [[10 * i] for i in range(12)]
+        assert [r.outcome.replica for r in reqs] == [0, 0, 1, 1] * 3
+        assert [r.outcome.wave for r in reqs] == [1] * 4 + [2] * 4 + [3] * 4
+
+    # (queue length, rids stolen) at batch 2: w waves leave floor(w/2)
+    @pytest.mark.parametrize("n,stolen", [
+        (1, [0]), (2, [0, 1]), (3, [2]), (4, [2, 3]), (5, [2, 3, 4]),
+        (6, [2, 3, 4, 5]),
+    ])
+    def test_claim_steals_whole_waves(self, n, stolen):
+        """An idle replica takes whole waves from the tail of the longest
+        queue, so no wave is split into narrower ones."""
+        sched = FleetScheduler([OneShotBackend(), OneShotBackend()],
+                               batch=2)
+        victim = [Req(i, [], 1) for i in range(n)]
+        ladders = [{}, {0: victim}]
+        got = sched._claim(0, ladders, [None, None])
+        assert [r.rid for r in got] == stolen
+        assert [r.rid for r in victim] == [i for i in range(n)
+                                           if i not in stolen]
+        assert sched.steals == 1
 
 
 class FleetScript(ScriptBackend):
@@ -349,21 +436,24 @@ class TestFleet:
         assert sched.steals == 0
 
     def test_fleet_of_one_matches_lockstep(self):
-        """One replica reproduces `LockstepScheduler.serve` exactly:
-        admission waves, stats counters, and emissions."""
-        mk = lambda: [Req(0, [1] * 8, 2), Req(1, [2] * 8, 6),
-                      Req(2, [3] * 8, 3)]
-        solo_be = ScriptBackend()
-        solo_reqs = mk()
-        solo = LockstepScheduler(solo_be, batch=2).serve(solo_reqs)
-        sched, bes, _ = _fleet(1, 2)
-        fleet_reqs = mk()
-        fleet = sched.serve(fleet_reqs)
-        assert [r.out for r in fleet_reqs] == [r.out for r in solo_reqs]
-        assert bes[0].started == solo_be.started
+        """One replica running ahead reproduces the synchronous tick of
+        the same backend behind a wrapper that hides dispatch/collect:
+        batches, stats, outputs and outcomes."""
+        def run(wrap):
+            be = OneShotBackend()
+            sched = FleetScheduler([wrap(be)], batch=2)
+            reqs = [Req(i, [], 1) for i in range(5)]
+            stats = sched.serve(reqs)
+            return be, sched, reqs, stats
+        ahead, a_sched, a_reqs, a_stats = run(lambda be: be)
+        step, s_sched, s_reqs, s_stats = run(StepOnly)
+        assert ahead.most_in_flight == 2 and step.most_in_flight == 1
+        assert ahead.waves == step.waves == [[0, 1], [2, 3], [4, None]]
+        assert [r.out for r in a_reqs] == [r.out for r in s_reqs]
         keys = ("steps", "finished", "backfills", "emissions")
-        assert [{k: s[k] for k in keys} for s in fleet] == \
-            [{k: s[k] for k in keys} for s in solo]
+        assert [{k: s[k] for k in keys} for s in a_stats] == \
+            [{k: s[k] for k in keys} for s in s_stats]
+        assert a_sched.outcomes == s_sched.outcomes
 
     def test_n_replica_outputs_match_single(self):
         """Every request's emission stream is identical however many

@@ -8,11 +8,13 @@ every test reuses them (eos_id is restored after mutation).
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_scheduler import StepOnly
 
 from repro.configs import get_config
-from repro.launch.scheduler import FleetScheduler, LockstepScheduler
+from repro.launch.scheduler import FleetScheduler
 from repro.launch.serve import (
-    CNNServer, ImageRequest, Request, Server, random_prompt_lengths,
+    CNNBackend, CNNServer, ImageRequest, Request, Server,
+    random_prompt_lengths,
 )
 from repro.models import graph as G
 
@@ -47,11 +49,11 @@ class TestLMServing:
 
     def test_retirement_frees_slot_for_queued_request(self, lm_server):
         """The headline regression: a short sequence retires mid-run and a
-        queued request is backfilled into its slot in the same lockstep
-        run — the run is bounded by the longest request, not the sum."""
+        queued request is backfilled into its slot in the same run — the
+        run is bounded by the longest request, not the sum."""
         reqs = _reqs(lm_server.cfg, [2, 6, 3])
         stats = lm_server.serve(reqs)
-        assert len(stats) == 1           # one lockstep run serves all three
+        assert len(stats) == 1           # one run serves all three
         s = stats[0]
         assert s["backfills"] == 1 and s["finished"] == 3
         assert [len(r.out) for r in reqs] == [2, 6, 3]
@@ -169,7 +171,7 @@ class TestLMServing:
 class TestCNNServing:
     def test_vgg_batched_serving_parity(self):
         """A mixed queue through SparseNet.apply with batch reuse: one
-        lockstep run, a backfilled fifth image, outputs matching the
+        run, a backfilled fifth image, outputs matching the
         direct batched apply."""
         cfg = get_config("vscnn-vgg16").reduce()
         srv = CNNServer(cfg, batch=4, seed=0)
@@ -298,33 +300,45 @@ class TestCNNServing:
             assert r.out == []
 
     def test_run_ahead_matches_dispatch_then_collect(self):
-        """The lockstep scheduler runs one wave ahead of its collects; the
-        one-replica fleet (a deadline puts it on that path) collects each
-        wave before it dispatches the next.  Over four waves, the last a
-        partial one, both deliver bit-identical logits and outcomes."""
+        """The server's scheduler runs one wave ahead of its collects; the
+        same backend behind a wrapper that hides dispatch/collect
+        collects each wave before it dispatches the next.  Over four
+        waves, the last a partial one, both form the same batches and
+        deliver bit-identical logits and outcomes."""
         cfg = get_config("vscnn-vgg16").reduce()
         rng = np.random.default_rng(6)
         imgs = [rng.standard_normal((32, 32, 3)).astype(np.float32)
                 for _ in range(13)]
+        srv = CNNServer(cfg, batch=4, seed=0)
+        assert type(srv.scheduler) is FleetScheduler
         served = []
-        for kw, kind in (({}, LockstepScheduler),
-                         ({"deadline_waves": 1000}, FleetScheduler)):
-            srv = CNNServer(cfg, batch=4, seed=0, **kw)
-            assert type(srv.scheduler) is kind
+        for sched in (srv.scheduler,
+                      FleetScheduler([StepOnly(srv.backend)], batch=4)):
             reqs = [ImageRequest(rid=i, image=im)
                     for i, im in enumerate(imgs)]
-            stats = srv.serve(reqs)
-            assert sum(s["steps"] for s in stats) == 4
+            stats = sched.serve(reqs)
+            assert [(s["steps"], s["backfills"]) for s in stats] == [(4, 9)]
             served.append(reqs)
         for a, b in zip(*served):
-            np.testing.assert_array_equal(a.logits, b.logits)
+            assert a.logits.tobytes() == b.logits.tobytes()
             assert a.out == b.out
             assert (a.outcome.status, a.outcome.reason, a.outcome.wave) == \
                 (b.outcome.status, b.outcome.reason, b.outcome.wave)
 
+    def test_one_replica_builds_a_plain_backend(self):
+        """One replica without shard_fc serves a plain `CNNBackend` over
+        the server's own params: no device copy, no mesh."""
+        cfg = get_config("vscnn-vgg16").reduce()
+        srv = CNNServer(cfg, batch=2, seed=0)
+        assert type(srv.backend) is CNNBackend
+        assert srv.backends == [srv.backend]
+        assert srv.backend.apply.mesh is None
+        assert srv.backend.apply.params is srv.params
+        assert srv.scheduler.backends == [srv.backend]
+
     def test_lockstep_max_queue_sheds(self):
-        """Bounded admission: requests beyond the depth are shed with a
-        queue_full refusal, the rest are served."""
+        """Bounded admission on a one-replica server: requests beyond the
+        depth are shed with a queue_full refusal, the rest are served."""
         cfg = get_config("vscnn-vgg16").reduce()
         srv = CNNServer(cfg, batch=2, seed=0, max_queue=3)
         s = cfg.image_size

@@ -63,7 +63,7 @@ class TestServer:
         srv = Server(cfg, batch=4, capacity=32)
         stats = srv.serve(reqs)
         # continuous batching: the fifth request backfills a retired slot,
-        # so one lockstep run serves all five (was 2 runs pre-backfill)
+        # so one run serves all five (was 2 runs pre-backfill)
         assert len(stats) == 1
         s = stats[0]
         assert s["backfills"] == 1 and s["finished"] == 5
